@@ -45,6 +45,7 @@ import sys
 
 from repro.core import ServerGroup
 from repro.eval import EvalGrid, EvalReport, evaluate
+from repro.launch.compile_cache import enable_compile_cache
 from repro.lint.sanitize import tracer_sanitizer
 from repro.obs import (
     Telemetry,
@@ -289,6 +290,7 @@ def main() -> int:
                     help="write a jax.profiler trace of the run to DIR")
     args = ap.parse_args()
 
+    enable_compile_cache()
     install_monitoring()
     with telemetry_session() as tel, profile_to(args.profile):
         if args.smoke:

@@ -30,6 +30,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.kernels.ref import flash_attention_ref
+from repro.launch.compile_cache import enable_compile_cache
 
 PEAK_FLOPS = 197e12
 HBM_BW = 819e9
@@ -288,6 +289,7 @@ def main(argv: list[str] | None = None) -> int:
     ap.add_argument("--smoke", action="store_true",
                     help="long-trace section only, CI-sized T/N")
     args = ap.parse_args(argv)
+    enable_compile_cache()
     rows: list[str] = []
     if args.smoke:
         provision_stream_long(rows, full=False)

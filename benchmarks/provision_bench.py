@@ -42,6 +42,7 @@ from repro.core import (
 )
 from repro.core.ski_rental import A1Deterministic
 from repro.kernels.provision_scan import provision_scan
+from repro.launch.compile_cache import enable_compile_cache
 from repro.lint.sanitize import tracer_sanitizer
 from repro.obs import CompileWatcher, profile_to, telemetry_session
 
@@ -391,6 +392,7 @@ def main() -> None:
     ap.add_argument("--profile", metavar="DIR", default=None,
                     help="write a jax.profiler trace of the run to DIR")
     args = ap.parse_args()
+    enable_compile_cache()
     rows: list[str] = []
     with profile_to(args.profile):
         (run_smoke if args.smoke else run)(rows)

@@ -38,7 +38,10 @@ def main() -> None:
         import paper_figs
         import provision_bench
 
+    from repro.launch.compile_cache import enable_compile_cache
     from repro.obs.jaxwatch import profile_to
+
+    enable_compile_cache()
 
     rows: list[str] = []
     with profile_to(args.profile):

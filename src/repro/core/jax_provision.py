@@ -57,7 +57,6 @@ import warnings
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from ..obs import provenance as _prov
@@ -859,14 +858,14 @@ def _sharded_grid(ab, predb, windows, delta, P_lv, beta_on_lv, beta_off_lv,
     if record:
         out_spec["decision_counts"] = P()
     cell_spec = (P(),) * 5
-    fn = shard_map(
+    fn = jax.shard_map(
         local,
         mesh=mesh,
         in_specs=(P(), P()) + cell_spec
         + (P(None, None, axis), P(None, axis), P(axis), P(axis), P(axis),
            P(axis), P(axis)),
         out_specs=out_spec,
-        check_rep=False,    # no replication rule for pallas_call yet
+        check_vma=False,    # no replication rule for pallas_call yet
     )
     out = fn(ab, pred_rows, cell_trace, cell_pred, cell_thr, cell_hor, cell_w,
              thresholds, horizon_wl, b, P_pad, bon_pad, boff_pad, route)
@@ -1055,14 +1054,14 @@ def _sharded_stream_grid(ab, predb, windows, delta, P_lv, beta_on_lv,
     if record:
         out_spec["decision_counts"] = P()
     cell_spec = (P(),) * 5
-    fn = shard_map(
+    fn = jax.shard_map(
         local,
         mesh=mesh,
         in_specs=(P(), P()) + cell_spec
         + (P(None, None, axis), P(None, axis), P(axis), P(axis), P(axis),
            P(axis), P(axis)),
         out_specs=out_spec,
-        check_rep=False,
+        check_vma=False,
     )
     out = fn(ab, pred_rows, cell_trace, cell_pred, cell_thr, cell_hor, cell_w,
              thresholds, horizon_wl, b, P_pad, bon_pad, boff_pad, route)

@@ -54,7 +54,7 @@ Two kernels share the slot semantics:
     VMEM block.  Memory is O(B·T) in SMEM, which caps the horizon long
     before HBM does — fine for planning windows, not for month-long traces.
   * :func:`provision_scan_stream` — the streaming layout: demand/predicted
-    rows live in HBM (``pltpu.ANY``) and are pulled in fixed ``t_chunk``
+    rows live in HBM (``pl.ANY``) and are pulled in fixed ``t_chunk``
     tiles with double-buffered async copies into SMEM/VMEM scratch; the
     per-level ``(run-length, on-bit, wait)`` state is carried across tiles
     in registers and returned to the caller, so a call's working set is
@@ -72,9 +72,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from ._compat import CompilerParams
-
 DEFAULT_BN = 128     # level-block width (lane dimension)
+LANE = 128           # TPU lane tile: Mosaic slices a row only in whole tiles
 
 #: default streaming tile length (slots per double-buffered DMA)
 DEFAULT_T_CHUNK = 512
@@ -136,9 +135,10 @@ def _grid_scan_kernel(
 
     def body(t, carry):
         if record:
-            r, on, wait, c_rise, c_wait, c_peek, c_off = carry
+            r, on_i, wait, c_rise, c_wait, c_peek, c_off = carry
         else:
-            r, on, wait = carry                     # (1, BN) f32, bool, f32
+            r, on_i, wait = carry                   # (1, BN) f32, int32, f32
+        on = on_i != 0                  # Mosaic cannot carry a bool vector
         busy = a_ref[b, t] > levels
         if record:
             # dispatcher turn-on edge; t=0 is the free initial state
@@ -158,18 +158,19 @@ def _grid_scan_kernel(
         off_now = expired & ~seen
         on = on & ~off_now
         r = jnp.where(off_now, 0.0, r)
-        o_ref[0, pl.ds(t, 1), :] = on.astype(jnp.int32)
+        on_i = on.astype(jnp.int32)
+        o_ref[0, pl.ds(t, 1), :] = on_i
         if record:
-            return (r, on, wait,
+            return (r, on_i, wait,
                     c_rise + rise.astype(jnp.int32),
                     c_wait + expired.astype(jnp.int32),
                     c_peek + (expired & seen).astype(jnp.int32),
                     c_off + off_now.astype(jnp.int32))
-        return (r, on, wait)
+        return (r, on_i, wait)
 
     init = (
         jnp.zeros((1, bn), jnp.float32),
-        jnp.zeros((1, bn), jnp.bool_),              # x(0) = a(0): busy turns it on
+        jnp.zeros((1, bn), jnp.int32),              # x(0) = a(0): busy turns it on
         jnp.zeros((1, bn), jnp.float32) if time_varying else m_ref[0, pl.ds(0, 1), :],
     )
     if record:
@@ -242,6 +243,7 @@ def provision_scan_grid(
         m3d = jnp.pad(m3d, ((0, 0), (0, 0), (0, pad_n)))
         h2d = jnp.pad(h2d, ((0, 0), (0, pad_n)))
         r2d = jnp.pad(r2d, ((0, 0), (0, pad_n)), constant_values=PAD_ROUTE)
+    h3d = h2d[:, None, :]           # (H, 1, NP): a legal (1, BN) tile per row
     a_pad = jnp.pad(traces, ((0, 0), (0, max_h)))
     p_pad = jnp.pad(predicted, ((0, 0), (0, max_h)))
     cells = tuple(jnp.asarray(c, jnp.int32) for c in
@@ -266,7 +268,7 @@ def provision_scan_grid(
         grid=(G, n_padded // bn),
         in_specs=[
             pl.BlockSpec((1, m3d.shape[1], bn), lambda g, j, *p: (p[2][g], 0, j)),
-            pl.BlockSpec((1, bn), lambda g, j, *p: (p[3][g], j)),
+            pl.BlockSpec((None, 1, bn), lambda g, j, *p: (p[3][g], 0, j)),
             pl.BlockSpec((1, bn), lambda g, j, *p: (0, j)),
         ],
         out_specs=out_specs,
@@ -275,11 +277,11 @@ def provision_scan_grid(
         kernel,
         grid_spec=grid_spec,
         out_shape=out_shape,
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel")
         ),
         interpret=interpret,
-    )(*cells, a_pad, p_pad, m3d, h2d, r2d)
+    )(*cells, a_pad, p_pad, m3d, h3d, r2d)
     if record:
         ons, counts = out
         return ons[:, :, :n].astype(bool), counts[:, :, :n]
@@ -289,19 +291,19 @@ def provision_scan_grid(
 def _stream_scan_kernel(
     cb_ref, cp_ref, ct_ref, ch_ref,   # scalar prefetch (SMEM): (G,) cell maps
     fl_ref,                           # scalar prefetch (SMEM): (2,) [fresh, n_levels]
-    a_hbm,                            # ANY: (B, T_pad) demand rows
-    p_hbm,                            # ANY: (R, T_pad + horizon) predicted rows
+    a_hbm,                            # ANY: (B, 1, T_pad) demand rows
+    p_hbm,                            # ANY: (R, 1, T_pad + p_ext) predicted rows
     m_ref,                            # ANY (K, T_pad, NP) waits | (1, 1, BN) VMEM block
     h_ref,                            # (1, BN) f32 per-level peek horizon (cell block)
     r_ref,                            # (1, BN) int32 routing ids (level block)
     si_ref,                           # (1, 2, BN) f32 carry in: rows [r, wait]
     oni_ref,                          # (1, BN) int32 carry in: on bits
-    x_hbm,                            # ANY out: (G, NBLK, T_pad) int32 x partials
+    x_hbm,                            # ANY out: (G, NBLK, 1, T_pad) int32 x partials
     acc_ref,                          # (1, n_acc, BN) int32 out: run/up/down [+counts]
     so_ref,                           # (1, 2, BN) f32 carry out: rows [r, wait]
     ono_ref,                          # (1, BN) int32 carry out: on bits
     *scratch,
-    T: int, t_chunk: int, n_tiles: int, bn: int, horizon: int,
+    T: int, t_chunk: int, n_tiles: int, bn: int, horizon: int, p_ext: int,
     time_varying: bool, record: bool,
 ):
     if time_varying:
@@ -319,15 +321,18 @@ def _stream_scan_kernel(
     h_row = h_ref[pl.ds(0, 1), :]
     lane_ok = levels < nlv
 
+    # every row a DMA moves sits alone in its (1, len) tile: Mosaic tiles
+    # the last two dims of a buffer, so rows, slots and level blocks are
+    # selected along untiled leading dims (the rings are (2, 1, len))
     def a_dma(slot, i):
         return pltpu.make_async_copy(
-            a_hbm.at[b, pl.ds(i * t_chunk, t_chunk)],
+            a_hbm.at[b, :, pl.ds(i * t_chunk, t_chunk)],
             a_scr.at[slot], a_sem.at[slot],
         )
 
     def p_dma(slot, i):
         return pltpu.make_async_copy(
-            p_hbm.at[pr, pl.ds(i * t_chunk, t_chunk + horizon)],
+            p_hbm.at[pr, :, pl.ds(i * t_chunk, t_chunk + p_ext)],
             p_scr.at[slot], p_sem.at[slot],
         )
 
@@ -340,7 +345,7 @@ def _stream_scan_kernel(
     def x_dma(slot, i):
         return pltpu.make_async_copy(
             x_scr.at[slot],
-            x_hbm.at[g, j, pl.ds(i * t_chunk, t_chunk)],
+            x_hbm.at[g, j, :, pl.ds(i * t_chunk, t_chunk)],
             x_sem.at[slot],
         )
 
@@ -358,7 +363,7 @@ def _stream_scan_kernel(
         wait0 = m_ref[0, pl.ds(0, 1), :]     # constant row; carry is redundant
     init = (
         si_ref[0, pl.ds(0, 1), :],           # r
-        oni_ref[pl.ds(0, 1), :] != 0,        # on
+        oni_ref[pl.ds(0, 1), :],             # on bits (int32: no bool carry)
         wait0,
     ) + tuple(jnp.zeros((1, bn), jnp.int32) for _ in range(7 if record else 3))
 
@@ -383,19 +388,21 @@ def _stream_scan_kernel(
 
         def slot_body(tl, s):
             if record:
-                r, on, wait, run, up, down, c1, c2, c3, c4 = s
+                r, on_i, wait, run, up, down, c1, c2, c3, c4 = s
             else:
-                r, on, wait, run, up, down = s
+                r, on_i, wait, run, up, down = s
+            on = on_i != 0
             t_glob = i * t_chunk + tl
             valid = t_glob < T                     # frozen tail of the pad
             first = fresh & (t_glob == 0)
-            busy = a_scr[slot, tl] > levels
+            busy = a_scr[slot, 0, tl] > levels
             # virtual boundary: x(0) = a(0) is the free initial state, so
             # at the very first slot of a fresh trace the previous on-state
             # is the busy pattern itself (no toggle, no rise) — matching
             # _cost_terms' first_on convention; a continuation call's
             # previous state is simply the carried on bits
-            prev_eff = jnp.where(first, busy, on)
+            # (selected as int32: Mosaic has no select between bool vectors)
+            prev_eff = jnp.where(first, busy.astype(jnp.int32), on_i) != 0
             if record:
                 rise = busy & ~on & ~first
             on_n = on | busy                       # dispatcher turn-on
@@ -410,21 +417,21 @@ def _stream_scan_kernel(
             r_n = jnp.where(idle, r_n + 1.0, r_n)
             seen = jnp.zeros_like(busy)
             for h in range(horizon):               # static unroll, <= max Delta
-                seen = seen | ((p_scr[slot, tl + 1 + h] > levels)
+                seen = seen | ((p_scr[slot, 0, tl + 1 + h] > levels)
                                & (float(h) < h_row))
             expired = idle & (r_n - 1.0 >= wait_n)
             off_now = expired & ~seen
             on_f = on_n & ~off_now
             r_n = jnp.where(off_now, 0.0, r_n)
             ok = on_f & lane_ok
-            x_scr[slot, tl] = jnp.sum(ok.astype(jnp.int32))
+            x_scr[slot, 0, tl] = jnp.sum(ok.astype(jnp.int32))
 
             def acc(tot, inc):
                 return jnp.where(valid, tot + inc.astype(jnp.int32), tot)
 
             out = (
                 jnp.where(valid, r_n, r),
-                jnp.where(valid, on_f, on),
+                jnp.where(valid, on_f.astype(jnp.int32), on_i),
                 jnp.where(valid, wait_n, wait),
                 acc(run, ok),
                 acc(up, on_f & ~prev_eff & lane_ok),
@@ -452,7 +459,7 @@ def _stream_scan_kernel(
 
     so_ref[0, pl.ds(0, 1), :] = final[0]
     so_ref[0, pl.ds(1, 1), :] = final[2]
-    ono_ref[pl.ds(0, 1), :] = final[1].astype(jnp.int32)
+    ono_ref[pl.ds(0, 1), :] = final[1]
     for k, tot in enumerate(final[3:]):
         acc_ref[0, pl.ds(k, 1), :] = tot
 
@@ -481,7 +488,7 @@ def provision_scan_stream(
 
     The same per-cell slot semantics as :func:`provision_scan_grid`, but
     the demand/predicted rows (and the (K, T, N) wait tables of the
-    randomized policies) stay in HBM (``pltpu.ANY``) and are streamed in
+    randomized policies) stay in HBM (``pl.ANY``) and are streamed in
     ``t_chunk``-slot tiles with double-buffered async copies; x(t) partials
     are DMA'd back out per tile.  Instead of the on-matrix, the kernel
     returns what the engine actually reduces it to:
@@ -501,16 +508,24 @@ def provision_scan_stream(
       in tests/test_streaming.py).
 
     ``T`` need not be a multiple of ``t_chunk`` — the pad tail freezes the
-    carry.  The peek reads ``horizon`` extra slots of each predicted tile,
-    so a chunk boundary never truncates the lookahead *within one call*;
-    across calls the caller chooses where to split (``provision_stream``
-    streams whole traces in one call, so no peek ever straddles a split).
+    carry.  The compiled route rounds ``t_chunk`` up to a multiple of 128
+    (Mosaic moves rows in whole lane tiles); results never depend on it.
+    The peek reads ``horizon`` extra slots of each predicted tile, so a
+    chunk boundary never truncates the lookahead *within one call*; across
+    calls the caller chooses where to split (``provision_stream`` streams
+    whole traces in one call, so no peek ever straddles a split).
     """
     traces = jnp.asarray(traces, jnp.int32)
     predicted = jnp.asarray(predicted, jnp.int32)
     assert traces.ndim == 2 and predicted.ndim == 2, (traces.shape, predicted.shape)
     T = traces.shape[1]
+    interpret = _resolve_interpret(interpret)
     t_chunk = int(min(t_chunk, max(T, 1)))
+    if not interpret:
+        # Mosaic slices a row tile only in whole 128-lane units
+        t_chunk = -(-t_chunk // LANE) * LANE
+    # peek lookahead past each tile, padded to whole lane tiles likewise
+    p_ext = -(-horizon // LANE) * LANE
     thresholds = jnp.asarray(thresholds, jnp.float32)
     assert thresholds.ndim == 3, thresholds.shape
     time_varying = thresholds.shape[1] != 1
@@ -555,29 +570,32 @@ def provision_scan_stream(
         c_w = jnp.pad(c_w, ((0, 0), (0, pad_n)))
     if time_varying:
         m3d = jnp.pad(m3d, ((0, 0), (0, T_pad - T), (0, 0)))
-    a_pad = jnp.pad(traces, ((0, 0), (0, T_pad - T)))
-    p_pad = jnp.pad(predicted, ((0, 0), (0, T_pad - T + horizon)))
+    a_pad = jnp.pad(traces, ((0, 0), (0, T_pad - T)))[:, None, :]
+    p_pad = jnp.pad(predicted, ((0, 0), (0, T_pad - T + p_ext)))[:, None, :]
     st_in = jnp.stack([c_r, c_w], axis=1)            # (G, 2, NP)
+    # (rows, 1, NP) with a squeezed row dim: legal (1, BN) tiles per row
+    h3d = h2d[:, None, :]
+    c_on = c_on[:, None, :]
     cells = tuple(jnp.asarray(c, jnp.int32) for c in
                   (cell_trace, cell_pred, cell_thr, cell_hor))
     flags = jnp.asarray([fresh, n_levels], jnp.int32)
-    interpret = _resolve_interpret(interpret)
     n_acc = 7 if record else 3
     nblk = n_padded // bn
 
     kernel = functools.partial(
         _stream_scan_kernel, T=T, t_chunk=t_chunk, n_tiles=n_tiles, bn=bn,
-        horizon=horizon, time_varying=time_varying, record=record,
+        horizon=horizon, p_ext=p_ext, time_varying=time_varying,
+        record=record,
     )
-    any_spec = pl.BlockSpec(memory_space=pltpu.ANY)
+    any_spec = pl.BlockSpec(memory_space=pl.ANY)
     m_spec = (
         any_spec if time_varying
         else pl.BlockSpec((1, 1, bn), lambda g, j, *p: (p[2][g], 0, j))
     )
     scratch = [
-        pltpu.SMEM((2, t_chunk), jnp.int32),             # a tiles
-        pltpu.SMEM((2, t_chunk + horizon), jnp.int32),   # p tiles (+ lookahead)
-        pltpu.SMEM((2, t_chunk), jnp.int32),             # x partials out
+        pltpu.SMEM((2, 1, t_chunk), jnp.int32),            # a tiles
+        pltpu.SMEM((2, 1, t_chunk + p_ext), jnp.int32),    # p tiles (+ lookahead)
+        pltpu.SMEM((2, 1, t_chunk), jnp.int32),            # x partials out
     ]
     if time_varying:
         scratch.append(pltpu.VMEM((2, t_chunk, bn), jnp.float32))
@@ -589,16 +607,16 @@ def provision_scan_stream(
             any_spec,                                            # a
             any_spec,                                            # p
             m_spec,                                              # thresholds
-            pl.BlockSpec((1, bn), lambda g, j, *p: (p[3][g], j)),  # horizon
+            pl.BlockSpec((None, 1, bn), lambda g, j, *p: (p[3][g], 0, j)),  # hor.
             pl.BlockSpec((1, bn), lambda g, j, *p: (0, j)),        # routes
             pl.BlockSpec((1, 2, bn), lambda g, j, *p: (g, 0, j)),  # r/wait in
-            pl.BlockSpec((1, bn), lambda g, j, *p: (g, j)),        # on in
+            pl.BlockSpec((None, 1, bn), lambda g, j, *p: (g, 0, j)),  # on in
         ],
         out_specs=[
             any_spec,                                              # x partials
             pl.BlockSpec((1, n_acc, bn), lambda g, j, *p: (g, 0, j)),
             pl.BlockSpec((1, 2, bn), lambda g, j, *p: (g, 0, j)),  # r/wait out
-            pl.BlockSpec((1, bn), lambda g, j, *p: (g, j)),        # on out
+            pl.BlockSpec((None, 1, bn), lambda g, j, *p: (g, 0, j)),  # on out
         ],
         scratch_shapes=scratch,
     )
@@ -606,24 +624,24 @@ def provision_scan_stream(
         kernel,
         grid_spec=grid_spec,
         out_shape=[
-            jax.ShapeDtypeStruct((G, nblk, T_pad), jnp.int32),
+            jax.ShapeDtypeStruct((G, nblk, 1, T_pad), jnp.int32),
             jax.ShapeDtypeStruct((G, n_acc, n_padded), jnp.int32),
             jax.ShapeDtypeStruct((G, 2, n_padded), jnp.float32),
-            jax.ShapeDtypeStruct((G, n_padded), jnp.int32),
+            jax.ShapeDtypeStruct((G, 1, n_padded), jnp.int32),
         ],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel")
         ),
         interpret=interpret,
-    )(*cells, flags, a_pad, p_pad, m3d, h2d, r2d, st_in, c_on)
-    x = x_part.sum(axis=1)[:, :T].astype(jnp.int32)
+    )(*cells, flags, a_pad, p_pad, m3d, h3d, r2d, st_in, c_on)
+    x = x_part.sum(axis=(1, 2))[:, :T].astype(jnp.int32)
     names = ("run", "up", "down")
     if record:
         names = names + ("demand_rise", "wait_expired", "peek_fired", "toggle_off")
     accs = {name: acc[:, k, :n] for k, name in enumerate(names)}
     carry_out = {
         "r": st_out[:, 0, :n],
-        "on": on_out[:, :n] != 0,
+        "on": on_out[:, 0, :n] != 0,
         "wait": st_out[:, 1, :n],
     }
     return x, accs, carry_out

@@ -5,9 +5,11 @@ pattern that used to be hand-rolled in three places (the eval harness's
 ``_engine_cache_size``, ``benchmarks/provision_bench.py``'s cache gates,
 and ``benchmarks/cr_eval.py``'s mesh smoke): snapshot the compiled-program
 count of a set of jitted functions, run something, and report how many
-programs the run added.  The engine's three entrypoints (``_run``,
-``_run_noise_sweep``, ``_sharded_grid``) are separate jitted functions
-*precisely so* their compiles are observable here.
+programs the run added.  The engine's entrypoints (``_run``,
+``_run_noise_sweep``, ``_sharded_grid`` and their streaming twins
+``_run_stream``, ``_run_stream_noise``, ``_sharded_stream_grid``) are
+separate jitted functions *precisely so* their compiles are observable
+here.
 
 The count rides JAX's private ``_cache_size`` API; when that API is gone
 the watcher degrades exactly like the code it replaced: ``snapshot()``
@@ -28,17 +30,26 @@ from .telemetry import Telemetry, get_telemetry
 
 
 def engine_fns() -> tuple:
-    """The provisioning engine's countable jitted entrypoints."""
-    from repro.core.jax_provision import _run, _run_noise_sweep, _sharded_grid
+    """The provisioning engine's countable jitted entrypoints: the
+    monolithic and streaming bodies of both routes."""
+    from repro.core.jax_provision import (
+        _run,
+        _run_noise_sweep,
+        _run_stream,
+        _run_stream_noise,
+        _sharded_grid,
+        _sharded_stream_grid,
+    )
 
-    return (_run, _run_noise_sweep, _sharded_grid)
+    return (_run, _run_noise_sweep, _sharded_grid,
+            _run_stream, _run_stream_noise, _sharded_stream_grid)
 
 
 class CompileWatcher:
     """Count compiled-program cache growth across a region.
 
-    ``fns``: the jitted functions to watch (default: the engine's three
-    entrypoints).  Use as a context manager::
+    ``fns``: the jitted functions to watch (default: :func:`engine_fns`).
+    Use as a context manager::
 
         with CompileWatcher() as w:
             provision(spec)

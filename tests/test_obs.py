@@ -179,6 +179,27 @@ def test_engine_cache_size_returns_int():
     assert isinstance(engine_cache_size(), int)
 
 
+@pytest.mark.parametrize("mesh", [False, True], ids=["scan", "mesh"])
+def test_default_watcher_counts_streaming_compiles(mesh):
+    """The default watch set covers provision_stream on both routes, so
+    recompile gates see the streaming bodies too."""
+    from repro.core import provision_stream
+
+    watch = CompileWatcher()
+    if not watch.available:
+        pytest.skip("private jit _cache_size API unavailable")
+    # a trace length no other test streams: the first call must compile
+    a = np.random.default_rng(7).integers(0, 9, size=53)
+    spec = _spec(a, 12, policy="delayedoff",
+                 mesh=jax.make_mesh((1,), ("data",)) if mesh else None)
+    with watch:
+        jax.block_until_ready(provision_stream(spec, t_chunk=11).x)
+    assert watch.added >= 1
+    with watch:
+        jax.block_until_ready(provision_stream(spec, t_chunk=11).x)
+    assert watch.added == 0
+
+
 def test_profile_to_none_is_noop():
     with profile_to(None):
         pass
