@@ -1,0 +1,9 @@
+"""Share of the traced live-stepper window in which no operation ran on the
+device."""
+
+
+def read(ctx):
+    run, tr = ctx["run"], ctx["trace"]
+    if run["kind"] != "live" or not tr or not tr["window_s"]:
+        return None
+    return 100.0 * (1.0 - tr["busy_s"] / tr["window_s"])
