@@ -330,110 +330,38 @@ def _prepare(spec: ProvisionSpec, pol: PolicySpec) -> dict:
     )
 
 
-def provision(spec: ProvisionSpec, *, record_decisions: bool = False) -> ProvisionResult:
-    """Run a :class:`ProvisionSpec` end-to-end as one jitted device program.
+def _spec_axes(out: dict, pr: dict, mesh: bool) -> dict:
+    """Squeeze an engine body's output back to the spec's axes.
 
-    Subsumes the deprecated ``provision_schedule`` / ``provision_sweep`` /
-    ``provision_sweep_costs`` / ``provision_cost`` /
-    ``provision_schedule_sharded`` surface: batching is the demand's leading
-    axis, the α-sweep is ``PolicySpec.windows``, sharding is ``mesh=``.  The
-    cost model's fields flow through jit as data, so re-pricing the fleet
-    does not recompile; only (policy, shapes, Δ's static scan bound) do.
-
-    ``record_decisions=True`` fills ``ProvisionResult.decisions`` /
-    ``decision_counts`` with per-slot reason codes out of the slot scan
-    (:mod:`repro.obs.provenance`); it is a *static* switch — the default-off
-    path traces exactly today's program, bit-for-bit and compile-for-compile
-    (gated in ``provision_bench.py --smoke``).  Rejected for ``offline``,
-    which is a closed form with no slot scan to record.
+    The mesh routes and the noise sweeps return the full (S, W, B, ...)
+    grid; the single-trace scan bodies return (W, B, ...) with no noise
+    axis.
     """
-    pol = spec.policy.validate()
-    if record_decisions and pol.name == "offline":
-        raise ValueError(
-            "record_decisions=True: 'offline' is the closed-form hindsight "
-            "optimum — it has no slot scan, so there are no per-slot "
-            "decisions to record"
-        )
-    pr = _prepare(spec, pol)
-    arrivals, defer = pr["arrivals"], pr["defer"]
-    ab, predb = pr["ab"], pr["predb"]
-    squeeze_b, squeeze_w, squeeze_s = (
-        pr["squeeze_b"], pr["squeeze_w"], pr["squeeze_s"]
-    )
-    windows, keys, n_levels, max_h = (
-        pr["windows"], pr["keys"], pr["n_levels"], pr["max_h"]
-    )
-    P_lv, bon_lv, boff_lv, delta_lv = (
-        pr["P_lv"], pr["bon_lv"], pr["boff_lv"], pr["delta_lv"]
-    )
+    grid = mesh or not pr["squeeze_s"]
+    lead = 1 if grid else 0
+    if pr["squeeze_b"]:
+        out = jax.tree.map(lambda o: jnp.squeeze(o, axis=lead + 1), out)
+    if pr["squeeze_w"]:
+        out = jax.tree.map(lambda o: jnp.squeeze(o, axis=lead), out)
+    if grid and pr["squeeze_s"]:
+        out = jax.tree.map(lambda o: jnp.squeeze(o, axis=0), out)
+    return out
 
-    tel = get_telemetry()
-    route = "mesh" if spec.mesh is not None else "scan"
-    with tel.span("provision", policy=pol.name, route=route,
-                  n_levels=n_levels, record=record_decisions):
-        if spec.mesh is not None:
-            # the fleet path takes the same (S, W, B) grid as the lax.scan
-            # programs: normalize predb to (S, B, T) and squeeze the result
-            # back to the spec's axis convention below
-            predb3 = predb[None] if predb.ndim == 2 else predb
-            out = _engine._sharded_run(
-                spec.mesh, spec.mesh_axis, ab, predb3, windows, delta_lv, P_lv,
-                bon_lv, boff_lv, n_levels=n_levels, max_h=max_h,
-                policy=pol.name, keys=keys, use_pallas=spec.use_pallas,
-                group_sizes=spec.costs.group_sizes, record=record_decisions,
-            )
 
-            def _squeeze(o):
-                if squeeze_b:
-                    o = jnp.squeeze(o, axis=2)
-                if squeeze_w:
-                    o = jnp.squeeze(o, axis=1)
-                if squeeze_s:
-                    o = jnp.squeeze(o, axis=0)
-                return o
+def _counts_from_rows(out: dict) -> dict:
+    """The aggregate per-level counters of a body that records them as
+    ``decision_counts`` rows (..., 4, N)."""
+    rows = out.pop("decision_counts")
+    return {name: rows[..., i, :] for i, name in enumerate(_prov.COUNT_ORDER)}
 
-            out = jax.tree.map(_squeeze, out)
-        else:
-            # noise sweep: the engine vmapped over the (S,) predicted axis
-            # with the demand, windows and keys held fixed — common random
-            # numbers across error levels, one compiled program for the
-            # whole (S, W, B) grid
-            body = _engine._run if squeeze_s else _engine._run_noise_sweep
-            out = body(
-                ab, predb, windows, delta_lv, P_lv, bon_lv, boff_lv, keys,
-                n_levels=n_levels, max_h=max_h, policy=pol.name,
-                record=record_decisions,
-            )
-            lead = 0 if squeeze_s else 1
-            if squeeze_b:
-                out = jax.tree.map(lambda o: jnp.squeeze(o, axis=lead + 1), out)
-            if squeeze_w:
-                out = jax.tree.map(lambda o: jnp.squeeze(o, axis=lead), out)
 
-    decisions = out.pop("decisions", None)
-    counts = None
-    if record_decisions:
-        if decisions is not None:
-            # lax.scan route: full per-slot codes; the aggregate counters
-            # are one reduction away (same rows the mesh route records)
-            counts = {
-                name: ((decisions & bit) != 0).sum(axis=-2).astype(jnp.int32)
-                for name, bit in zip(_prov.COUNT_ORDER, _prov.COUNT_BITS)
-            }
-        else:
-            rows = out.pop("decision_counts")       # (..., 4, N) int32
-            counts = {
-                name: rows[..., i, :]
-                for i, name in enumerate(_prov.COUNT_ORDER)
-            }
-        offs = counts["toggle_off"]
-        if tel.enabled and not isinstance(offs, jax.core.Tracer):
-            tel.count("provision/decision_toggle_offs", float(offs.sum()))
-
+def _result(spec: ProvisionSpec, pr: dict, out: dict, decisions,
+            counts) -> ProvisionResult:
+    """Per-level and fleet costs, queue metrics and the result, from the
+    engine's per-level energy and toggle totals."""
     level_cost = out["energy"] + out["on_cost"] + out["off_cost"]
-    queue = (
-        {} if defer is None else defer.metrics(arrivals, out["x"])
-    )
+    defer = pr["defer"]
+    queue = {} if defer is None else defer.metrics(pr["arrivals"], out["x"])
     return ProvisionResult(
         x=out["x"],
         cost=level_cost.sum(axis=-1),
@@ -452,6 +380,82 @@ def provision(spec: ProvisionSpec, *, record_decisions: bool = False) -> Provisi
         decisions=decisions,
         decision_counts=counts,
     )
+
+
+def provision(spec: ProvisionSpec, *, record_decisions: bool = False) -> ProvisionResult:
+    """Run a :class:`ProvisionSpec` end-to-end as one jitted device program.
+
+    Subsumes the deprecated ``provision_schedule`` / ``provision_sweep`` /
+    ``provision_sweep_costs`` / ``provision_cost`` /
+    ``provision_schedule_sharded`` surface: batching is the demand's leading
+    axis, the α-sweep is ``PolicySpec.windows``, sharding is ``mesh=``.  The
+    cost model's fields flow through jit as data, so re-pricing the fleet
+    does not recompile; only (policy, shapes, Δ's static scan bound) do.
+
+    ``record_decisions=True`` fills ``ProvisionResult.decisions`` /
+    ``decision_counts`` with per-slot reason codes out of the slot scan
+    (:mod:`repro.obs.provenance`); it is a *static* switch — the default-off
+    path traces exactly today's program, bit-for-bit and compile-for-compile
+    (gated in ``provision_bench.py --smoke``).  Rejected for ``offline``,
+    which is a closed form with no slot scan to record.
+    """
+    tel = get_telemetry()
+    route = "mesh" if spec.mesh is not None else "scan"
+    with tel.span("provision", policy=spec.policy.name, route=route,
+                  record=record_decisions) as outer:
+        pol = spec.policy.validate()
+        if record_decisions and pol.name == "offline":
+            raise ValueError(
+                "record_decisions=True: 'offline' is the closed-form hindsight "
+                "optimum — it has no slot scan, so there are no per-slot "
+                "decisions to record"
+            )
+        with tel.span("provision/prepare"):
+            pr = _prepare(spec, pol)
+        n_levels = pr["n_levels"]
+        outer.set(n_levels=n_levels)
+        engine_in = (pr["ab"], pr["predb"], pr["windows"], pr["delta_lv"],
+                     pr["P_lv"], pr["bon_lv"], pr["boff_lv"])
+        with tel.span("provision/dispatch"):
+            if spec.mesh is not None:
+                # the fleet path takes the same (S, W, B) grid as the
+                # lax.scan programs: predb normalized to (S, B, T)
+                ab, predb, *rest = engine_in
+                predb3 = predb[None] if predb.ndim == 2 else predb
+                out = _engine._sharded_run(
+                    spec.mesh, spec.mesh_axis, ab, predb3, *rest,
+                    n_levels=n_levels, max_h=pr["max_h"], policy=pol.name,
+                    keys=pr["keys"], use_pallas=spec.use_pallas,
+                    group_sizes=spec.costs.group_sizes,
+                    record=record_decisions,
+                )
+            else:
+                # noise sweep: the engine vmapped over the (S,) predicted
+                # axis with the demand, windows and keys held fixed —
+                # common random numbers across error levels, one compiled
+                # program for the whole (S, W, B) grid
+                body = _engine._run if pr["squeeze_s"] else _engine._run_noise_sweep
+                out = body(
+                    *engine_in, pr["keys"], n_levels=n_levels,
+                    max_h=pr["max_h"], policy=pol.name,
+                    record=record_decisions,
+                )
+        with tel.span("provision/finish"):
+            out = _spec_axes(out, pr, mesh=spec.mesh is not None)
+            decisions = out.pop("decisions", None)
+            counts = None
+            if record_decisions:
+                if decisions is not None:
+                    # lax.scan route: full per-slot codes; the aggregate
+                    # counters are one reduction away (same rows the mesh
+                    # route records)
+                    counts = {
+                        name: ((decisions & bit) != 0).sum(axis=-2).astype(jnp.int32)
+                        for name, bit in zip(_prov.COUNT_ORDER, _prov.COUNT_BITS)
+                    }
+                else:
+                    counts = _counts_from_rows(out)
+            return _result(spec, pr, out, decisions, counts)
 
 
 def provision_stream(
@@ -488,99 +492,48 @@ def provision_stream(
     """
     from repro.kernels.provision_scan import DEFAULT_T_CHUNK
 
-    pol = spec.policy.validate()
-    if pol.name == "offline":
-        raise ValueError(
-            "provision_stream is online-only: 'offline' is the closed-form "
-            "hindsight optimum over the whole trace — use provision()"
-        )
-    pr = _prepare(spec, pol)
-    arrivals, defer = pr["arrivals"], pr["defer"]
-    ab, predb = pr["ab"], pr["predb"]
-    squeeze_b, squeeze_w, squeeze_s = (
-        pr["squeeze_b"], pr["squeeze_w"], pr["squeeze_s"]
-    )
-    windows, keys, n_levels, max_h = (
-        pr["windows"], pr["keys"], pr["n_levels"], pr["max_h"]
-    )
-    P_lv, bon_lv, boff_lv, delta_lv = (
-        pr["P_lv"], pr["bon_lv"], pr["boff_lv"], pr["delta_lv"]
-    )
-    T = int(ab.shape[-1])
-    if t_chunk is None:
-        t_chunk = DEFAULT_T_CHUNK
-    t_chunk = int(min(max(int(t_chunk), 1), max(T, 1)))
-
     tel = get_telemetry()
     route = "mesh" if spec.mesh is not None else "scan"
-    with tel.span("provision_stream", policy=pol.name, route=route,
-                  n_levels=n_levels, t_chunk=t_chunk,
-                  record=record_decisions):
-        if spec.mesh is not None:
-            predb3 = predb[None] if predb.ndim == 2 else predb
-            out = _engine._sharded_stream(
-                spec.mesh, spec.mesh_axis, ab, predb3, windows, delta_lv, P_lv,
-                bon_lv, boff_lv, n_levels=n_levels, max_h=max_h,
-                policy=pol.name, keys=keys, use_pallas=spec.use_pallas,
-                group_sizes=spec.costs.group_sizes, t_chunk=t_chunk,
-                record=record_decisions,
+    with tel.span("provision_stream", policy=spec.policy.name, route=route,
+                  record=record_decisions) as outer:
+        pol = spec.policy.validate()
+        if pol.name == "offline":
+            raise ValueError(
+                "provision_stream is online-only: 'offline' is the closed-form "
+                "hindsight optimum over the whole trace — use provision()"
             )
-
-            def _squeeze(o):
-                if squeeze_b:
-                    o = jnp.squeeze(o, axis=2)
-                if squeeze_w:
-                    o = jnp.squeeze(o, axis=1)
-                if squeeze_s:
-                    o = jnp.squeeze(o, axis=0)
-                return o
-
-            out = jax.tree.map(_squeeze, out)
-        else:
-            body = (
-                _engine._run_stream if squeeze_s else _engine._run_stream_noise
-            )
-            out = body(
-                ab, predb, windows, delta_lv, P_lv, bon_lv, boff_lv, keys,
-                n_levels=n_levels, max_h=max_h, policy=pol.name,
-                t_chunk=t_chunk, record=record_decisions,
-            )
-            lead = 0 if squeeze_s else 1
-            if squeeze_b:
-                out = jax.tree.map(lambda o: jnp.squeeze(o, axis=lead + 1), out)
-            if squeeze_w:
-                out = jax.tree.map(lambda o: jnp.squeeze(o, axis=lead), out)
-
-    counts = None
-    if record_decisions:
-        rows = out.pop("decision_counts")           # (..., 4, N) int32
-        counts = {
-            name: rows[..., i, :]
-            for i, name in enumerate(_prov.COUNT_ORDER)
-        }
-        offs = counts["toggle_off"]
-        if tel.enabled and not isinstance(offs, jax.core.Tracer):
-            tel.count("provision/decision_toggle_offs", float(offs.sum()))
-
-    level_cost = out["energy"] + out["on_cost"] + out["off_cost"]
-    queue = (
-        {} if defer is None else defer.metrics(arrivals, out["x"])
-    )
-    return ProvisionResult(
-        x=out["x"],
-        cost=level_cost.sum(axis=-1),
-        energy=out["energy"].sum(axis=-1),
-        toggle_cost=(out["on_cost"] + out["off_cost"]).sum(axis=-1),
-        level_cost=level_cost,
-        group_cost=(
-            None if spec.costs.group_sizes is None
-            else spec.costs.group_reduce(level_cost)
-        ),
-        backlog=queue.get("backlog"),
-        max_delay=queue.get("max_delay"),
-        p99_delay=queue.get("p99_delay"),
-        deadline_misses=queue.get("deadline_misses"),
-        unserved=queue.get("unserved"),
-        decisions=None,
-        decision_counts=counts,
-    )
+        with tel.span("provision/prepare"):
+            pr = _prepare(spec, pol)
+        n_levels = pr["n_levels"]
+        T = int(pr["ab"].shape[-1])
+        if t_chunk is None:
+            t_chunk = DEFAULT_T_CHUNK
+        t_chunk = int(min(max(int(t_chunk), 1), max(T, 1)))
+        outer.set(n_levels=n_levels, t_chunk=t_chunk)
+        engine_in = (pr["ab"], pr["predb"], pr["windows"], pr["delta_lv"],
+                     pr["P_lv"], pr["bon_lv"], pr["boff_lv"])
+        with tel.span("provision/dispatch"):
+            if spec.mesh is not None:
+                ab, predb, *rest = engine_in
+                predb3 = predb[None] if predb.ndim == 2 else predb
+                out = _engine._sharded_stream(
+                    spec.mesh, spec.mesh_axis, ab, predb3, *rest,
+                    n_levels=n_levels, max_h=pr["max_h"], policy=pol.name,
+                    keys=pr["keys"], use_pallas=spec.use_pallas,
+                    group_sizes=spec.costs.group_sizes, t_chunk=t_chunk,
+                    record=record_decisions,
+                )
+            else:
+                body = (
+                    _engine._run_stream if pr["squeeze_s"]
+                    else _engine._run_stream_noise
+                )
+                out = body(
+                    *engine_in, pr["keys"], n_levels=n_levels,
+                    max_h=pr["max_h"], policy=pol.name, t_chunk=t_chunk,
+                    record=record_decisions,
+                )
+        with tel.span("provision/finish"):
+            out = _spec_axes(out, pr, mesh=spec.mesh is not None)
+            counts = _counts_from_rows(out) if record_decisions else None
+            return _result(spec, pr, out, None, counts)

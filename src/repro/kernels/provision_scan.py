@@ -281,6 +281,7 @@ def provision_scan_grid(
             dimension_semantics=("parallel", "parallel")
         ),
         interpret=interpret,
+        name="provision_scan_grid",
     )(*cells, a_pad, p_pad, m3d, h3d, r2d)
     if record:
         ons, counts = out
@@ -633,6 +634,7 @@ def provision_scan_stream(
             dimension_semantics=("parallel", "parallel")
         ),
         interpret=interpret,
+        name="provision_scan_stream",
     )(*cells, flags, a_pad, p_pad, m3d, h3d, r2d, st_in, c_on)
     x = x_part.sum(axis=(1, 2))[:, :T].astype(jnp.int32)
     names = ("run", "up", "down")

@@ -18,7 +18,10 @@ and one no-op call when nobody is collecting.  That is the **zero-overhead
 contract** (docs/observability.md): telemetry never allocates, never times,
 and — crucially — never crosses the jit boundary when disabled.  Spans wrap
 *host-side* work (a ``provision`` call, a benchmark cell); in-graph
-provenance is :mod:`repro.obs.provenance`'s job.
+provenance is :mod:`repro.obs.provenance`'s job.  On a live registry a span
+is also a ``jax.profiler.TraceAnnotation``: under a profiler trace
+(:func:`repro.obs.profile_to`) the program's phases sit on the host plane
+beside the device's ops, on one clock.
 
 Enable collection for a region with::
 
@@ -35,6 +38,7 @@ a (name, labels) pair is one series.  All methods are thread-safe.
 from __future__ import annotations
 
 import contextlib
+import itertools
 import json
 import os
 import pathlib
@@ -44,6 +48,13 @@ import time
 
 def _label_key(labels: dict) -> tuple:
     return tuple(sorted((k, str(v)) for k, v in labels.items()))
+
+
+class _OpenSpans(threading.local):
+    """The spans open on each thread, innermost last."""
+
+    def __init__(self) -> None:
+        self.spans: list = []
 
 
 class Telemetry:
@@ -58,6 +69,8 @@ class Telemetry:
         self._hists: dict[tuple, list[float]] = {}
         self._events: list[dict] = []
         self._t0_ns = time.perf_counter_ns()
+        self._open = _OpenSpans()
+        self._calls = itertools.count()
 
     # ------------------------------------------------------------- metrics
     def count(self, name: str, value: float = 1.0, **labels) -> None:
@@ -99,39 +112,20 @@ class Telemetry:
     def _now_us(self) -> float:
         return (time.perf_counter_ns() - self._t0_ns) / 1e3
 
-    @contextlib.contextmanager
-    def span(self, name: str, **args):
+    def span(self, name: str, **args) -> "_Span":
         """Time a host-side region: a Chrome "X" event + a duration sample.
 
         The duration (ms) also lands in histogram ``span/<name>``, so p50/
-        p99 of a repeated span are one :meth:`quantile` call away.
+        p99 of a repeated span are one :meth:`quantile` call away.  A span
+        opened inside another on the same thread records the enclosing
+        span's name as ``args.parent``; every span under one outermost span
+        shares its integer ``args.call``.  The region is also a
+        ``jax.profiler.TraceAnnotation``, so while a profiler trace runs
+        the span sits on the trace's host plane, on the device ops' clock.
+        Use as ``with tel.span(...) as sp:``; ``sp.set(k=v)`` adds args
+        known only inside the region.
         """
-        ts = self._now_us()
-        try:
-            yield self
-        finally:
-            dur = self._now_us() - ts
-            ev = {
-                "name": name, "ph": "X", "ts": ts, "dur": dur,
-                "pid": os.getpid(), "tid": threading.get_ident(),
-                "cat": "repro",
-            }
-            if args:
-                ev["args"] = {k: str(v) for k, v in args.items()}
-            with self._lock:
-                self._events.append(ev)
-            self.observe(f"span/{name}", dur / 1e3)
-
-    def instant(self, name: str, **args) -> None:
-        """Mark a point in time (Chrome "i" instant event)."""
-        ev = {
-            "name": name, "ph": "i", "ts": self._now_us(), "s": "p",
-            "pid": os.getpid(), "tid": threading.get_ident(), "cat": "repro",
-        }
-        if args:
-            ev["args"] = {k: str(v) for k, v in args.items()}
-        with self._lock:
-            self._events.append(ev)
+        return _Span(self, name, args)
 
     # ------------------------------------------------------------- exports
     def chrome_trace(self) -> dict:
@@ -180,9 +174,73 @@ class Telemetry:
         return path
 
 
-@contextlib.contextmanager
-def _noop_span(tel):
-    yield tel
+def _annotation(name: str):
+    """``jax.profiler.TraceAnnotation(name)``; jax is imported here, not at
+    module load (the registry itself needs no jax)."""
+    from jax.profiler import TraceAnnotation
+
+    return TraceAnnotation(name)
+
+
+class _Span:
+    """One live span (see :meth:`Telemetry.span`)."""
+
+    __slots__ = ("_tel", "name", "args", "_ts", "_parent", "_call", "_ann")
+
+    def __init__(self, tel: Telemetry, name: str, args: dict) -> None:
+        self._tel, self.name, self.args = tel, name, args
+
+    def set(self, **args) -> None:
+        """Add or replace args of the span's event."""
+        self.args.update(args)
+
+    def __enter__(self) -> "_Span":
+        stack = self._tel._open.spans
+        if stack:
+            self._parent, self._call = stack[-1].name, stack[-1]._call
+        else:
+            self._parent, self._call = None, next(self._tel._calls)
+        stack.append(self)
+        self._ann = _annotation(self.name)
+        self._ann.__enter__()
+        self._ts = self._tel._now_us()
+        return self
+
+    def __exit__(self, *exc) -> None:
+        tel = self._tel
+        dur = tel._now_us() - self._ts
+        self._ann.__exit__(*exc)
+        tel._open.spans.pop()
+        ev_args = {k: str(v) for k, v in self.args.items()}
+        if self._parent is not None:
+            ev_args["parent"] = self._parent
+        ev_args["call"] = self._call
+        ev = {
+            "name": self.name, "ph": "X", "ts": self._ts, "dur": dur,
+            "pid": os.getpid(), "tid": threading.get_ident(),
+            "cat": "repro", "args": ev_args,
+        }
+        with tel._lock:
+            tel._events.append(ev)
+        tel.observe(f"span/{self.name}", dur / 1e3)
+
+
+class _NullSpan:
+    """The disabled span: one shared instance, no timing, no annotation."""
+
+    __slots__ = ()
+
+    def set(self, **args) -> None:
+        pass
+
+    def __enter__(self) -> "_NullSpan":
+        return self
+
+    def __exit__(self, *exc) -> None:
+        pass
+
+
+_NULL_SPAN = _NullSpan()
 
 
 class NullTelemetry(Telemetry):
@@ -217,10 +275,7 @@ class NullTelemetry(Telemetry):
         return None
 
     def span(self, name, **args):
-        return _noop_span(self)
-
-    def instant(self, name, **args):
-        pass
+        return _NULL_SPAN
 
     def chrome_trace(self):
         return {"traceEvents": [], "displayTimeUnit": "ms"}

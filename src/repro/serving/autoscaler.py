@@ -366,7 +366,9 @@ class FleetProvisioner:
         and the queue scalars (``deadline_misses``/``unserved``/delay
         quantiles) are *cumulative since the first call*.  Every step
         records plan latency, toggles (including the seam from the
-        previous chunk) and backlog depth into ``self.metrics``.
+        previous chunk) and backlog depth into ``self.metrics``; its phases
+        are telemetry spans under ``serving/advance``
+        (docs/observability.md).
         """
         import time
 
@@ -381,116 +383,128 @@ class FleetProvisioner:
         from repro.obs.telemetry import get_telemetry
         from .stepper import pow2_bucket, stepper_chunk, stepper_init
 
-        chunk = np.asarray(demand_chunk, np.int64)
-        if chunk.ndim != 1:
-            raise ValueError(
-                f"advance() steps one fleet: demand_chunk must be (T,), "
-                f"got shape {chunk.shape}"
-            )
-        if chunk.size == 0:
-            raise ValueError("advance() needs at least one demand slot")
-        if self.policy.name == "offline":
-            raise ValueError(
-                "advance() steps online policies; 'offline' needs the whole "
-                "trace in hindsight — use plan()"
-            )
-        if self.policy.windows is not None:
-            raise ValueError(
-                "the planner's PolicySpec carries a windows= sweep; advance() "
-                "steps a single window — use plan_sweep()/sweep_costs(), or "
-                "drop windows from the PolicySpec"
-            )
-        if self.deferral is not None and np.ndim(self.deferral.slack) != 0:
-            raise ValueError(
-                "advance() streams with scalar slack only (a per-slot slack "
-                "vector is tied to one fixed horizon) — use plan()"
-            )
-        arrivals = self._as_i32(chunk)
-        n = chunk.size
-        max_h = self.costs.delta_slots()
-        delta_lv = jnp.broadcast_to(
-            jnp.asarray(self.costs.delta, jnp.float32), (self.max_replicas,)
-        )
-        if self.state is None:
-            self.state = stepper_init(
-                self.max_replicas, delta_lv, policy=self.policy.name,
-                window=self.policy.window, deferral=self.deferral,
-            )
-        st = self.state
-        t_pad = pow2_bucket(n)
-        pad = np.zeros(t_pad, np.int32)
-        valid = np.arange(t_pad) < n
-
-        with get_telemetry().span("serving/advance", chunk=n, t_pad=t_pad,
-                                  t0=st.t):
+        tel = get_telemetry()
+        with tel.span("serving/advance") as outer:
             t_wall = time.perf_counter()
-            if self.deferral is None:
-                served, defer_c = arrivals, None
-            else:
-                apad = jnp.asarray(
-                    np.concatenate([np.asarray(arrivals), pad[n:]]))
-                served_pad, defer_c = defer_stream(
-                    apad, st.defer, slack=self.deferral.bound(),
-                    cap=self.deferral.cap, valid=jnp.asarray(valid),
+            with tel.span("serving/advance/prepare"):
+                chunk = np.asarray(demand_chunk, np.int64)
+                if chunk.ndim != 1:
+                    raise ValueError(
+                        f"advance() steps one fleet: demand_chunk must be (T,), "
+                        f"got shape {chunk.shape}"
+                    )
+                if chunk.size == 0:
+                    raise ValueError("advance() needs at least one demand slot")
+                if self.policy.name == "offline":
+                    raise ValueError(
+                        "advance() steps online policies; 'offline' needs the "
+                        "whole trace in hindsight — use plan()"
+                    )
+                if self.policy.windows is not None:
+                    raise ValueError(
+                        "the planner's PolicySpec carries a windows= sweep; "
+                        "advance() steps a single window — use plan_sweep()/"
+                        "sweep_costs(), or drop windows from the PolicySpec"
+                    )
+                if self.deferral is not None and np.ndim(self.deferral.slack) != 0:
+                    raise ValueError(
+                        "advance() streams with scalar slack only (a per-slot "
+                        "slack vector is tied to one fixed horizon) — use plan()"
+                    )
+                arrivals = self._as_i32(chunk)
+                n = chunk.size
+                max_h = self.costs.delta_slots()
+                delta_lv = jnp.broadcast_to(
+                    jnp.asarray(self.costs.delta, jnp.float32), (self.max_replicas,)
                 )
-                served = served_pad[:n]
-            a_pad = jnp.asarray(
-                np.concatenate([np.asarray(served, np.int32), pad[n:]]))
-            x_pad, (r, on, wait), totals = stepper_chunk(
-                a_pad, jnp.int32(n), jnp.int32(st.t), self.policy.key,
-                st.r, st.on, st.wait, delta_lv,
-                policy=self.policy.name, n_levels=self.max_replicas,
-                max_h=max_h, window=self.policy.window, t_pad=t_pad,
-            )
-            x = np.asarray(x_pad)[:n]
+                if self.state is None:
+                    self.state = stepper_init(
+                        self.max_replicas, delta_lv, policy=self.policy.name,
+                        window=self.policy.window, deferral=self.deferral,
+                    )
+                st = self.state
+                t_pad = pow2_bucket(n)
+                outer.set(chunk=n, t_pad=t_pad, t0=st.t)
+                pad = np.zeros(t_pad, np.int32)
+                valid = np.arange(t_pad) < n
+
+                def padded(v):
+                    return jnp.asarray(
+                        np.concatenate([np.asarray(v, np.int32), pad[n:]]))
+
+                if self.deferral is None:
+                    served, defer_c = arrivals, None
+                    a_pad = padded(served)
+                else:
+                    apad = padded(arrivals)
+            if self.deferral is not None:
+                with tel.span("serving/advance/dispatch"):
+                    served_pad, defer_c = defer_stream(
+                        apad, st.defer, slack=self.deferral.bound(),
+                        cap=self.deferral.cap, valid=jnp.asarray(valid),
+                    )
+                    served = served_pad[:n]
+                with tel.span("serving/advance/prepare"):
+                    a_pad = padded(served)
+            with tel.span("serving/advance/dispatch"):
+                x_pad, (r, on, wait), totals = stepper_chunk(
+                    a_pad, jnp.int32(n), jnp.int32(st.t), self.policy.key,
+                    st.r, st.on, st.wait, delta_lv,
+                    policy=self.policy.name, n_levels=self.max_replicas,
+                    max_h=max_h, window=self.policy.window, t_pad=t_pad,
+                )
+            with tel.span("serving/advance/fetch"):
+                x = np.asarray(x_pad)[:n]
             queue_c, backlog, qsnap = None, None, {}
             if self.deferral is not None:
-                xq = jnp.asarray(np.concatenate([x.astype(np.int32), pad[n:]]))
-                backlog_pad, queue_c = queue_stream(
-                    apad, xq, st.queue, rule=self.deferral.rule,
-                    max_slack=self.deferral.bound(), valid=jnp.asarray(valid),
+                with tel.span("serving/advance/dispatch"):
+                    xq = padded(x)
+                    backlog_pad, queue_c = queue_stream(
+                        apad, xq, st.queue, rule=self.deferral.rule,
+                        max_slack=self.deferral.bound(), valid=jnp.asarray(valid),
+                    )
+                with tel.span("serving/advance/fetch"):
+                    backlog = jnp.asarray(backlog_pad)[:n]
+            with tel.span("serving/advance/cost"):
+                if self.deferral is not None:
+                    qsnap = queue_stream_finalize(
+                        queue_c, max_slack=self.deferral.bound())
+                P_lv, bon_lv, boff_lv = self.costs.per_level(self.max_replicas)
+                level_cost = (
+                    P_lv * totals["run"] + bon_lv * totals["up"]
+                    + boff_lv * totals["down"]
                 )
-                backlog = jnp.asarray(backlog_pad)[:n]
-                qsnap = queue_stream_finalize(
-                    queue_c, max_slack=self.deferral.bound())
-            latency_ms = (time.perf_counter() - t_wall) * 1e3
-
-        self.state = dataclasses.replace(
-            st, t=st.t + n, r=r, on=on, wait=wait,
-            defer=defer_c, queue=queue_c,
-        )
-        self._history = np.concatenate([self._history, chunk])
-        P_lv, bon_lv, boff_lv = self.costs.per_level(self.max_replicas)
-        level_cost = (
-            P_lv * totals["run"] + bon_lv * totals["up"]
-            + boff_lv * totals["down"]
-        )
-        self.last_plan = ProvisionResult(
-            x=jnp.asarray(x),
-            cost=level_cost.sum(),
-            energy=(P_lv * totals["run"]).sum(),
-            toggle_cost=(
-                bon_lv * totals["up"] + boff_lv * totals["down"]
-            ).sum(),
-            level_cost=level_cost,
-            group_cost=(
-                None if self.costs.group_sizes is None
-                else self.costs.group_reduce(level_cost)
-            ),
-            backlog=backlog,
-            max_delay=qsnap.get("max_delay"),
-            p99_delay=qsnap.get("p99_delay"),
-            deadline_misses=qsnap.get("deadline_misses"),
-            unserved=qsnap.get("unserved"),
-        )
-        toggles = int(np.abs(np.diff(x)).sum())
-        if self._prev_x is not None:
-            toggles += abs(int(x[0]) - self._prev_x)    # seam between chunks
-        self._prev_x = int(x[-1])
-        self.metrics.observe_plan(
-            latency_ms, toggles,
-            0 if backlog is None else int(np.asarray(backlog)[-1]),
-        )
+                self.last_plan = ProvisionResult(
+                    x=jnp.asarray(x),
+                    cost=level_cost.sum(),
+                    energy=(P_lv * totals["run"]).sum(),
+                    toggle_cost=(
+                        bon_lv * totals["up"] + boff_lv * totals["down"]
+                    ).sum(),
+                    level_cost=level_cost,
+                    group_cost=(
+                        None if self.costs.group_sizes is None
+                        else self.costs.group_reduce(level_cost)
+                    ),
+                    backlog=backlog,
+                    max_delay=qsnap.get("max_delay"),
+                    p99_delay=qsnap.get("p99_delay"),
+                    deadline_misses=qsnap.get("deadline_misses"),
+                    unserved=qsnap.get("unserved"),
+                )
+            with tel.span("serving/advance/record"):
+                self.state = dataclasses.replace(
+                    st, t=st.t + n, r=r, on=on, wait=wait,
+                    defer=defer_c, queue=queue_c,
+                )
+                self._history = np.concatenate([self._history, chunk])
+                toggles = int(np.abs(np.diff(x)).sum())
+                if self._prev_x is not None:
+                    toggles += abs(int(x[0]) - self._prev_x)    # seam between chunks
+                self._prev_x = int(x[-1])
+                depth = 0 if backlog is None else int(np.asarray(backlog)[-1])
+                latency_ms = (time.perf_counter() - t_wall) * 1e3
+                self.metrics.observe_plan(latency_ms, toggles, depth)
         return x
 
     def reset(self) -> None:

@@ -14,10 +14,10 @@ Exports: Python-side accessors (``latency_quantile(0.99)``, ``.toggles``,
 Prometheus `text exposition format
 <https://prometheus.io/docs/instrumenting/exposition_formats/>`__, ready to
 serve from a ``/metrics`` endpoint (summary with p50/p99 quantile labels
-for latency, counters for plans/toggles, a gauge for backlog).  Metrics
-also mirror into the active :mod:`repro.obs.telemetry` registry when one is
-installed, so a benchmark's Chrome trace and a serving loop's Prometheus
-scrape come from the same instrumentation.
+for latency, counters for plans/toggles, a gauge for backlog).  Toggles
+and backlog depth also mirror into the active :mod:`repro.obs.telemetry`
+registry when one is installed; the latency is there already, as the
+``span/serving/advance`` histogram of the step's own span.
 """
 from __future__ import annotations
 
@@ -56,7 +56,6 @@ class PlanMetrics:
         self.backlog_depth = int(backlog)
         self.peak_backlog = max(self.peak_backlog, int(backlog))
         tel = get_telemetry()
-        tel.observe("serving/plan_latency_ms", float(latency_ms))
         tel.count("serving/toggles", int(toggles))
         tel.gauge("serving/backlog_depth", int(backlog))
 

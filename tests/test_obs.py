@@ -102,7 +102,6 @@ def test_trace_and_metrics_files_round_trip(tmp_path):
     tel = Telemetry()
     with tel.span("phase"):
         tel.count("n")
-    tel.instant("marker")
     tp = tel.write_chrome_trace(tmp_path / "t.json")
     mp = tel.write_metrics_jsonl(tmp_path / "m.jsonl")
     loaded = json.loads(tp.read_text())
@@ -360,6 +359,89 @@ def test_provision_spans_reach_telemetry():
     assert len(tel.samples("span/provision")) == 1
 
 
+def _events(tel, name):
+    return [e for e in tel.chrome_trace()["traceEvents"] if e["name"] == name]
+
+
+PLAN_PHASES = ("provision/prepare", "provision/dispatch", "provision/finish")
+ADVANCE_PHASES = tuple(f"serving/advance/{p}" for p in
+                       ("prepare", "dispatch", "fetch", "cost", "record"))
+
+
+@pytest.mark.parametrize("entry", ["provision", "provision_stream"])
+def test_planning_entries_span_their_phases(entry):
+    """Each entry opens prepare, dispatch and finish once, nested in its
+    outer span (same call, the outer span as parent), and the phases take
+    no more than the whole call."""
+    from repro.core import provision_stream
+
+    fn = {"provision": provision, "provision_stream": provision_stream}[entry]
+    a = msr_like_trace(np.random.default_rng(6), n_slots=80, mean_jobs=6.0)
+    with telemetry_session() as tel:
+        jax.block_until_ready(fn(_spec(a, 16)).x)
+    (outer,) = _events(tel, entry)
+    assert outer["args"]["n_levels"] == "16" and "parent" not in outer["args"]
+    total = 0.0
+    for name in PLAN_PHASES:
+        (ev,) = _events(tel, name)
+        assert ev["args"]["parent"] == entry
+        assert ev["args"]["call"] == outer["args"]["call"]
+        assert outer["ts"] <= ev["ts"] and ev["ts"] + ev["dur"] <= outer["ts"] + outer["dur"]
+        total += ev["dur"]
+    assert total <= outer["dur"]
+
+
+def test_advance_spans_its_phases_and_times_the_whole_tick():
+    from repro.serving import FleetProvisioner
+
+    rng = np.random.default_rng(1)
+    planner = FleetProvisioner(COSTS, policy="delayedoff", max_replicas=16)
+    planner.advance(rng.integers(0, 12, size=4))                 # warm
+    with telemetry_session() as tel:
+        for _ in range(3):
+            planner.advance(rng.integers(0, 12, size=4))
+    ticks = tel.samples("span/serving/advance")
+    phases = {name: tel.samples(f"span/{name}") for name in ADVANCE_PHASES}
+    assert len(ticks) == 3 and all(len(v) == 3 for v in phases.values())
+    calls = {e["args"]["call"] for e in _events(tel, "serving/advance")}
+    for name in ADVANCE_PHASES:
+        evs = _events(tel, name)
+        assert {e["args"]["parent"] for e in evs} == {"serving/advance"}
+        assert {e["args"]["call"] for e in evs} == calls
+    latencies = planner.metrics.plan_latencies_ms[1:]
+    for i, lat in enumerate(latencies):
+        first_four = sum(phases[name][i] for name in ADVANCE_PHASES[:4])
+        assert first_four <= lat <= ticks[i]
+
+
+@pytest.mark.parametrize("live", [True, False], ids=["live", "default"])
+def test_spans_reach_the_profilers_host_plane_only_when_live(tmp_path, live):
+    import contextlib
+    import glob
+
+    from jax.profiler import ProfileData
+
+    a = msr_like_trace(np.random.default_rng(7), n_slots=80, mean_jobs=6.0)
+    spec = _spec(a, 16)
+    jax.block_until_ready(provision(spec).x)                      # warm
+    with jax.profiler.trace(str(tmp_path)):
+        with telemetry_session() if live else contextlib.nullcontext():
+            jax.block_until_ready(provision(spec).x)
+    (path,) = glob.glob(str(tmp_path / "**" / "*.xplane.pb"), recursive=True)
+    host = {ev.name for plane in ProfileData.from_file(path).planes
+            if plane.name.startswith("/host:")
+            for line in plane.lines for ev in line.events}
+    assert ("provision/prepare" in host) == live
+    assert any(n.startswith("provision/") for n in host) == live
+
+
+def test_disabled_span_is_one_shared_noop():
+    assert NullTelemetry().span("a") is NullTelemetry().span("b")
+    with NullTelemetry().span("a") as sp:
+        sp.set(n=1)
+    assert NullTelemetry().chrome_trace()["traceEvents"] == []
+
+
 # --------------------------------------------------------- serving metrics
 
 
@@ -387,5 +469,5 @@ def test_plan_metrics_mirror_into_telemetry():
         m.observe_plan(12.5, toggles=4, backlog=2)
     assert tel.counter_value("serving/toggles") == 4
     assert tel.gauge_value("serving/backlog_depth") == 2
-    assert tel.samples("serving/plan_latency_ms") == [12.5]
+    assert m.plan_latencies_ms == [12.5]
     assert m.peak_backlog == 2

@@ -82,8 +82,11 @@ def test_kernel_compiles_for_v5e(topo, aot, kernel, time_varying):
         return provision_scan_stream(a, p, m, ct, cp, cthr, chor, **kw)
 
     args = _kernel_args(SingleDeviceSharding(topo.devices[0]), time_varying)
-    compiled = jax.jit(run).lower(*args).compile()
-    assert "tpu_custom_call" in compiled.as_text()
+    text = jax.jit(run).lower(*args).compile().as_text()
+    assert "tpu_custom_call" in text
+    # the kernel's name is the custom call's instruction name, which is
+    # what a device trace's op events carry
+    assert f"%provision_scan_{kernel}" in text
 
 
 def test_sharded_stream_grid_compiles_on_four_chips(topo, aot):
