@@ -152,7 +152,8 @@ def streaming_latency(smoke: bool) -> list:
         prov.metrics = PlanMetrics()
         # hard zero-recompile gate on the warmed steady state (RecompileError
         # on violation), while watch.added still feeds the report row
-        with tracer_sanitizer(fns=(stepper.stepper_chunk,)) as watch:
+        with tracer_sanitizer(fns=(stepper.stepper_chunk,
+                                   stepper.stepper_tick)) as watch:
             for i in range(1, chunks + 1):
                 prov.advance(demand[i * t_chunk:(i + 1) * t_chunk])
         rows.append(StreamingRow(

@@ -277,14 +277,14 @@ def stepper_phase(demand, ref: dict) -> None:
     from repro.core import PAPER_COSTS
     from repro.obs import CompileWatcher
     from repro.obs.jaxwatch import engine_fns
-    from repro.serving import FleetProvisioner, stepper_chunk
+    from repro.serving import FleetProvisioner, stepper_chunk, stepper_tick
 
     a = np.asarray(demand)
     fleet = FleetProvisioner(PAPER_COSTS, policy="delayedoff",
                              max_replicas=N_LEVELS)
     xs, t = [], 0
     for rnd in ("warm-up", "steady"):
-        watch = CompileWatcher(fns=engine_fns() + (stepper_chunk,))
+        watch = CompileWatcher(fns=engine_fns() + (stepper_chunk, stepper_tick))
         with watch:
             for n in CHUNKS:
                 t0 = time.perf_counter()

@@ -13,7 +13,13 @@ from .autoscaler import (
 from .cluster import ClusterReport, make_window_max_predictor, run_cluster
 from .engine import GenerationResult, InferenceEngine
 from .metrics import PlanMetrics
-from .stepper import StepperState, pow2_bucket, stepper_chunk, stepper_init
+from .stepper import (
+    StepperState,
+    pow2_bucket,
+    stepper_chunk,
+    stepper_init,
+    stepper_tick,
+)
 
 __all__ = [
     "FleetProvisioner",
@@ -25,6 +31,7 @@ __all__ = [
     "replica_cost_model",
     "stepper_chunk",
     "stepper_init",
+    "stepper_tick",
     "ClusterReport",
     "make_window_max_predictor",
     "run_cluster",

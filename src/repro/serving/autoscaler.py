@@ -283,6 +283,8 @@ class FleetProvisioner:
         #: StepperState`); None until the first advance() call
         self.state = None
         self._prev_x = None
+        #: (cost model, its device copy, Δ, scan bound) for advance()
+        self._dev_costs = None
         from .metrics import PlanMetrics
 
         #: rolling advance() health: plan-latency p50/p99, toggle churn,
@@ -364,15 +366,20 @@ class FleetProvisioner:
         chunk, the cost fields are chunk-local (toggle edges against the
         carried state; no forced final-off — the trace has not ended),
         and the queue scalars (``deadline_misses``/``unserved``/delay
-        quantiles) are *cumulative since the first call*.  Every step
-        records plan latency, toggles (including the seam from the
-        previous chunk) and backlog depth into ``self.metrics``; its phases
-        are telemetry spans under ``serving/advance``
+        quantiles) are *cumulative since the first call*.  The scan and
+        the chunk's costs are one compiled program
+        (:func:`~repro.serving.stepper.stepper_tick`) on a device copy of
+        the cost model made once per model: a tick uploads its padded
+        chunk, ``n`` and ``t0`` as one array, its one sync is the fetch
+        of ``x``, and ``last_plan.x`` is that fetch put back explicitly.
+        Every step records plan latency, toggles (including the seam from
+        the previous chunk) and backlog depth into ``self.metrics``; its
+        phases are telemetry spans under ``serving/advance``
         (docs/observability.md).
         """
         import time
 
-        import jax.numpy as jnp
+        import jax
 
         from repro.core import ProvisionResult
         from repro.deferral import (
@@ -381,7 +388,7 @@ class FleetProvisioner:
             queue_stream_finalize,
         )
         from repro.obs.telemetry import get_telemetry
-        from .stepper import pow2_bucket, stepper_chunk, stepper_init
+        from .stepper import pow2_bucket, stepper_init, stepper_tick, tick_input
 
         tel = get_telemetry()
         with tel.span("serving/advance") as outer:
@@ -411,81 +418,51 @@ class FleetProvisioner:
                         "advance() streams with scalar slack only (a per-slot "
                         "slack vector is tied to one fixed horizon) — use plan()"
                     )
-                arrivals = self._as_i32(chunk)
+                self._check_peak(chunk)
                 n = chunk.size
-                max_h = self.costs.delta_slots()
-                delta_lv = jnp.broadcast_to(
-                    jnp.asarray(self.costs.delta, jnp.float32), (self.max_replicas,)
-                )
+                costs, delta, max_h = self._tick_costs()
                 if self.state is None:
                     self.state = stepper_init(
-                        self.max_replicas, delta_lv, policy=self.policy.name,
+                        self.max_replicas, delta, policy=self.policy.name,
                         window=self.policy.window, deferral=self.deferral,
                     )
                 st = self.state
                 t_pad = pow2_bucket(n)
                 outer.set(chunk=n, t_pad=t_pad, t0=st.t)
-                pad = np.zeros(t_pad, np.int32)
-                valid = np.arange(t_pad) < n
-
-                def padded(v):
-                    return jnp.asarray(
-                        np.concatenate([np.asarray(v, np.int32), pad[n:]]))
-
+                tick_in = tick_input(chunk, st.t, t_pad)
                 if self.deferral is None:
-                    served, defer_c = arrivals, None
-                    a_pad = padded(served)
+                    tick_in = jax.device_put(tick_in)
                 else:
-                    apad = padded(arrivals)
-            if self.deferral is not None:
-                with tel.span("serving/advance/dispatch"):
-                    served_pad, defer_c = defer_stream(
-                        apad, st.defer, slack=self.deferral.bound(),
-                        cap=self.deferral.cap, valid=jnp.asarray(valid),
-                    )
-                    served = served_pad[:n]
-                with tel.span("serving/advance/prepare"):
-                    a_pad = padded(served)
+                    tick_in, a_pad, valid = jax.device_put(
+                        (tick_in, tick_in[:t_pad], np.arange(t_pad) < n))
             with tel.span("serving/advance/dispatch"):
-                x_pad, (r, on, wait), totals = stepper_chunk(
-                    a_pad, jnp.int32(n), jnp.int32(st.t), self.policy.key,
-                    st.r, st.on, st.wait, delta_lv,
-                    policy=self.policy.name, n_levels=self.max_replicas,
-                    max_h=max_h, window=self.policy.window, t_pad=t_pad,
+                served_pad, defer_c, queue_c = None, None, None
+                if self.deferral is not None:
+                    served_pad, defer_c = defer_stream(
+                        a_pad, st.defer, slack=self.deferral.bound(),
+                        cap=self.deferral.cap, valid=valid,
+                    )
+                x_pad, (r, on, wait), fields = stepper_tick(
+                    tick_in, self.policy.key, st.r, st.on, st.wait, costs,
+                    delta, served_pad, policy=self.policy.name, max_h=max_h,
+                    window=self.policy.window,
                 )
+                if self.deferral is not None:
+                    backlog_pad, queue_c = queue_stream(
+                        a_pad, x_pad, st.queue, rule=self.deferral.rule,
+                        max_slack=self.deferral.bound(), valid=valid,
+                    )
             with tel.span("serving/advance/fetch"):
                 x = np.asarray(x_pad)[:n]
-            queue_c, backlog, qsnap = None, None, {}
-            if self.deferral is not None:
-                with tel.span("serving/advance/dispatch"):
-                    xq = padded(x)
-                    backlog_pad, queue_c = queue_stream(
-                        apad, xq, st.queue, rule=self.deferral.rule,
-                        max_slack=self.deferral.bound(), valid=jnp.asarray(valid),
-                    )
-                with tel.span("serving/advance/fetch"):
-                    backlog = jnp.asarray(backlog_pad)[:n]
+                backlog = None if self.deferral is None else backlog_pad[:n]
             with tel.span("serving/advance/cost"):
-                if self.deferral is not None:
-                    qsnap = queue_stream_finalize(
-                        queue_c, max_slack=self.deferral.bound())
-                P_lv, bon_lv, boff_lv = self.costs.per_level(self.max_replicas)
-                level_cost = (
-                    P_lv * totals["run"] + bon_lv * totals["up"]
-                    + boff_lv * totals["down"]
-                )
+                qsnap = {} if self.deferral is None else queue_stream_finalize(
+                    queue_c, max_slack=self.deferral.bound())
                 self.last_plan = ProvisionResult(
-                    x=jnp.asarray(x),
-                    cost=level_cost.sum(),
-                    energy=(P_lv * totals["run"]).sum(),
-                    toggle_cost=(
-                        bon_lv * totals["up"] + boff_lv * totals["down"]
-                    ).sum(),
-                    level_cost=level_cost,
-                    group_cost=(
-                        None if self.costs.group_sizes is None
-                        else self.costs.group_reduce(level_cost)
-                    ),
+                    # explicit: an eager x_pad[:n] would upload its start
+                    # index and compile once per chunk length
+                    x=jax.device_put(x),
+                    **fields,
                     backlog=backlog,
                     max_delay=qsnap.get("max_delay"),
                     p99_delay=qsnap.get("p99_delay"),
@@ -507,6 +484,22 @@ class FleetProvisioner:
                 self.metrics.observe_plan(latency_ms, toggles, depth)
         return x
 
+    def _tick_costs(self):
+        """``(costs, delta, max_h)`` for :func:`~repro.serving.stepper.
+        stepper_tick`: the cost model's fields and its Δ as float32 device
+        arrays, and the scan's static peek bound — made once per cost
+        model, not once per tick."""
+        import jax
+
+        if self._dev_costs is None or self._dev_costs[0] is not self.costs:
+            c = self.costs
+            f32 = dataclasses.replace(c, **{
+                k: np.asarray(getattr(c, k), np.float32)
+                for k in ("P", "beta_on", "beta_off")})
+            dev = jax.device_put((f32, np.asarray(c.delta, np.float32)))
+            self._dev_costs = (c, *dev, c.delta_slots())
+        return self._dev_costs[1:]
+
     def reset(self) -> None:
         """Drop the advance() carry and history — the next call starts a
         fresh trace (compiled steps stay warm; state is data)."""
@@ -518,13 +511,16 @@ class FleetProvisioner:
     def _as_i32(self, demand):
         import jax.numpy as jnp
 
-        a = jnp.asarray(np.asarray(demand), jnp.int32)
-        peak = int(np.asarray(demand).max())
+        demand = np.asarray(demand)
+        self._check_peak(demand)
+        return jnp.asarray(demand, jnp.int32)
+
+    def _check_peak(self, demand: np.ndarray) -> None:
+        peak = int(demand.max())
         if peak > self.max_replicas and self.deferral is None:
             # with a deferral spec the service cap (== the fleet size by
             # default) absorbs the excess into the backlog instead
             raise ValueError(f"demand peak {peak} exceeds max_replicas {self.max_replicas}")
-        return a
 
 
 def replica_cost_model(

@@ -37,6 +37,8 @@ Zero steady-state recompiles: chunks are padded to power-of-two buckets
 (:func:`pow2_bucket`, tail masked by an ``n_valid`` operand that is jit
 *data*), so any mix of chunk sizes within a warmed bucket reuses the
 compiled step — gated by a compile-count test in tests/test_streaming.py.
+A tick is one compiled program, :func:`stepper_tick`: the scan and the
+chunk's per-level and fleet costs together.
 """
 from __future__ import annotations
 
@@ -45,6 +47,7 @@ import functools
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from repro.core.jax_provision import (
     KEYED,
@@ -185,3 +188,60 @@ def stepper_chunk(a_pad, n_valid, t0, key, r, on, wait, delta_lv, *,
         slot, (r, on, wait, z, z, z), jnp.arange(t_pad)
     )
     return x, (r, on, wait), {"run": run, "up": up, "down": down}
+
+
+def tick_input(chunk, t0: int, t_pad: int) -> np.ndarray:
+    """The host half of :func:`stepper_tick`'s one upload: a ``(t_pad + 2,)``
+    int32 array holding ``chunk`` zero-padded to ``t_pad`` slots, then the
+    chunk's length, then ``t0``, its global start slot."""
+    out = np.zeros(t_pad + 2, np.int32)
+    out[:len(chunk)] = chunk
+    out[t_pad:] = len(chunk), t0
+    return out
+
+
+@functools.partial(jax.jit, static_argnames=("policy", "max_h", "window"))
+def stepper_tick(tick_in, key, r, on, wait, costs, delta, served=None, *,
+                 policy, max_h, window):
+    """One ``advance()`` tick as one compiled program: the
+    :func:`stepper_chunk` scan and the chunk's costs.
+
+    ``tick_in``: :func:`tick_input`'s array on the device, so a tick's
+    demand, length and start slot are one transfer (its length sets the
+    bucket ``t_pad``); ``served``: with deferral, the ``(t_pad,)`` service
+    profile the scan steps on in place of the chunk's arrivals.
+    ``costs``: the planner's :class:`~repro.core.CostModel` with its
+    fields as float32 arrays — they are jit data, so re-pricing does not
+    recompile, while a typed model's ``group_sizes`` is static structure;
+    ``delta``: its critical interval Δ, scalar or per-level, as the host
+    computed it (the same float32 values ``provision()`` steps on).  The
+    other arguments are :func:`stepper_chunk`'s; its ``n_levels`` is the
+    carry's length.
+
+    Returns ``(x, (r, on, wait), fields)``: ``fields`` holds the chunk's
+    ``level_cost`` (N,) float32, its sums ``cost``/``energy``/
+    ``toggle_cost``, and ``group_cost`` (d,) — None for an ungrouped
+    model.  Each per-level product is formed once and feeds both the
+    per-level and the fleet totals.
+    """
+    t_pad = tick_in.shape[0] - 2
+    a_pad = tick_in[:t_pad] if served is None else served
+    n_levels = r.shape[0]
+    x, carry, totals = stepper_chunk(
+        a_pad, tick_in[t_pad], tick_in[t_pad + 1], key, r, on, wait, delta,
+        policy=policy, n_levels=n_levels, max_h=max_h, window=window,
+        t_pad=t_pad,
+    )
+    P_lv, bon_lv, boff_lv = costs.per_level(n_levels)
+    energy = P_lv * totals["run"]
+    up = bon_lv * totals["up"]
+    down = boff_lv * totals["down"]
+    level_cost = energy + up + down
+    return x, carry, {
+        "cost": level_cost.sum(),
+        "energy": energy.sum(),
+        "toggle_cost": (up + down).sum(),
+        "level_cost": level_cost,
+        "group_cost": (None if costs.group_sizes is None
+                       else costs.group_reduce(level_cost)),
+    }

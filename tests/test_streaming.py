@@ -273,13 +273,107 @@ def test_advance_chunk_cost_plus_final_off_matches_plan(demand):
     assert got == pytest.approx(float(ref.cost))
 
 
+_LEVEL_RNG = np.random.default_rng(17)
+#: planners whose ticks the cost and transfer tests step: the paper's
+#: scalar model, per-level (N,) cost arrays, a two-type fleet, a keyed
+#: policy, and deferral.  Costs are whole numbers, so every float32 sum
+#: below is exact in any order.
+TICK_CASES = {
+    "paper": dict(costs=PAPER_COSTS, policy="delayedoff"),
+    "per-level": dict(costs=CostModel(
+        P=_LEVEL_RNG.integers(1, 3, 18).astype(np.float32),
+        beta_on=_LEVEL_RNG.integers(1, 4, 18).astype(np.float32),
+        beta_off=_LEVEL_RNG.integers(1, 4, 18).astype(np.float32),
+    ), policy="A1", window=2),
+    "typed": dict(costs=CostModel.from_groups(
+        ServerGroup("old", 10, P=2.0, beta_on=4.0, beta_off=2.0),
+        ServerGroup("new", 8, P=1.0, beta_on=3.0, beta_off=3.0),
+    ), policy="AQ-det"),
+    "A3-key": dict(costs=PAPER_COSTS, policy="A3", window=1, key=KEY),
+    "deferral": dict(costs=PAPER_COSTS, policy="A1",
+                     deferral=DeferralSpec(slack=3)),
+}
+
+
+def _tick_planner(case):
+    kw = dict(TICK_CASES[case])
+    return FleetProvisioner(kw.pop("costs"), max_replicas=18, **kw)
+
+
+@pytest.mark.parametrize("case", sorted(TICK_CASES))
+def test_advance_costs_equal_the_eager_formulas(demand, case):
+    """Every ``last_plan`` cost field of a tick equals the per-level
+    formulas applied with numpy to ``stepper_chunk``'s run/up/down totals
+    on the same chunk and carry; ``level_cost`` bit for bit, and ``x`` is
+    the scan's schedule."""
+    prov = _tick_planner(case)
+    c, pol, spec = prov.costs, prov.policy, prov.deferral
+    delta = jnp.asarray(c.delta, jnp.float32)
+    ref = stepper.stepper_init(18, delta, policy=pol.name, window=pol.window,
+                               deferral=spec)
+    P, bon, boff = (np.broadcast_to(np.asarray(f, np.float32), (18,))
+                    for f in (c.P, c.beta_on, c.beta_off))
+    a, pos = np.asarray(demand), 0
+    for n in (5, 11, 1, 8):
+        t_pad = pow2_bucket(n)
+        a_pad = jnp.asarray(np.pad(a[pos:pos + n], (0, t_pad - n)), jnp.int32)
+        defer = None
+        if spec is not None:
+            a_pad, defer = defer_stream(
+                a_pad, ref.defer, slack=spec.bound(), cap=spec.cap,
+                valid=jnp.arange(t_pad) < n)
+        x, (r, on, wait), tot = stepper.stepper_chunk(
+            a_pad, jnp.int32(n), jnp.int32(pos), pol.key, ref.r, ref.on,
+            ref.wait, delta, policy=pol.name, n_levels=18,
+            max_h=c.delta_slots(), window=pol.window, t_pad=t_pad)
+        ref = stepper.StepperState(t=pos + n, r=r, on=on, wait=wait,
+                                   defer=defer)
+        run, up, down = (np.asarray(tot[k]).astype(np.float32)
+                         for k in ("run", "up", "down"))
+        level_cost = P * run + bon * up + boff * down
+
+        got_x = prov.advance(a[pos:pos + n])
+        plan = prov.last_plan
+        assert (got_x == np.asarray(x)[:n]).all(), (case, pos)
+        assert (np.asarray(plan.x) == got_x).all(), (case, pos)
+        lc = np.asarray(plan.level_cost)
+        assert lc.dtype == np.float32
+        assert (lc.view(np.uint32) == level_cost.view(np.uint32)).all(), (case, pos)
+        assert float(plan.cost) == level_cost.sum(dtype=np.float64)
+        assert float(plan.energy) == (P * run).sum(dtype=np.float64)
+        assert float(plan.toggle_cost) == \
+            (bon * up + boff * down).sum(dtype=np.float64)
+        if c.group_sizes is None:
+            assert plan.group_cost is None
+        else:
+            bounds = np.cumsum((0,) + c.group_sizes)
+            want = [level_cost[lo:hi].sum() for lo, hi in zip(bounds, bounds[1:])]
+            assert np.asarray(plan.group_cost).tolist() == want
+        pos += n
+
+
+@pytest.mark.parametrize("case", ["paper", "per-level", "typed", "A3-key"])
+def test_warmed_advance_makes_no_implicit_upload(demand, case):
+    """Once a tick has run, the next one uploads only through its explicit
+    ``device_put``s: no numpy argument to a jitted program, no
+    ``jnp.asarray`` of host data, no ``jnp.int32`` scalars.  (Deferral
+    keeps its eager ``defer_stream``/``queue_stream`` dispatches.)"""
+    a = np.asarray(demand)
+    prov, ref = _tick_planner(case), _tick_planner(case)
+    prov.advance(a[:3])
+    ref.advance(a[:3])
+    with jax.transfer_guard_host_to_device("disallow"):
+        x = prov.advance(a[3:8])
+    assert (x == ref.advance(a[3:8])).all()
+
+
 def test_advance_zero_recompiles_in_warmed_bucket(demand, tracer_sanitizer):
     """The satellite gate: after one warmup call, three *different* chunk
     sizes inside the same pow2 bucket add zero jit traces."""
     a = np.asarray(demand)
     prov = FleetProvisioner(PAPER_COSTS, policy="A1", max_replicas=18)
     prov.advance(a[:8])                             # warmup owns bucket 8
-    with tracer_sanitizer(fns=(stepper.stepper_chunk,)):
+    with tracer_sanitizer(fns=(stepper.stepper_chunk, stepper.stepper_tick)):
         prov.advance(a[8:13])                       # 5 -> bucket 8
         prov.advance(a[13:16])                      # 3 -> bucket 8
         prov.advance(a[16:24])                      # 8 -> bucket 8
