@@ -17,13 +17,19 @@ Every phase goes through the entry points a user calls:
                   mesh=...)``, plus one long trace (A1, T = 2^20, N = 128);
 * live stepper -- ``FleetProvisioner.advance()`` on the 10,240-level fleet
                   at chunk sizes 1, 64 and 1024, with 0 recompiles allowed
-                  after warm-up.
+                  after warm-up;
+* typed fleet  -- four server generations (12,391 levels, the benchmark's
+                  ``msr-dc-typed4``) under AQ-rand with an explicit key,
+                  through ``provision_stream(spec, mesh=...)`` against
+                  ``provision(spec)`` on the scan route.
 
 Correctness gates ``ok``: every Pallas result equals the scan route (``x``
 exactly, ``level_cost`` to rtol 1e-6); the scan route's A1 and delayedoff
 schedules and costs equal the numpy reference ``fluid_scan``; every online
 cost over the offline optimum lies within the paper's bound for its alpha;
-the stepper's schedule equals the scan route's.  The route is checked too:
+the stepper's schedule equals the scan route's; the typed fleet's streaming
+kernel equals the scan route bit for bit on ``x``, ``level_cost`` and
+``group_cost``.  The route is checked too:
 the ``kernels/pallas_interpret`` gauge reads 0 in every Pallas phase and the
 compiled programs hold a ``tpu_custom_call``.
 
@@ -64,6 +70,10 @@ LONG_N = 128
 LONG_MEAN_JOBS = 26.0        # peak about 120 < LONG_N
 #: ``FleetProvisioner.advance()`` chunk sizes
 CHUNKS = (1, 64, 1024)
+#: the typed fleet: (name, servers, P) of four generations, beta = 3 each
+TYPED4 = (("type1", 6_732, 1.0), ("type2", 3_863, 1.25),
+          ("type3", 1_001, 1.5), ("type4", 795, 2.0))
+TYPED4_MEAN_JOBS = 2_614.0   # peak about 12,100 < 12,391 levels
 
 
 class SmokeFailure(AssertionError):
@@ -105,7 +115,10 @@ def observe(label: str, fn):
 def host(res) -> dict:
     import numpy as np
 
-    return {"x": np.asarray(res.x), "level_cost": np.asarray(res.level_cost)}
+    out = {"x": np.asarray(res.x), "level_cost": np.asarray(res.level_cost)}
+    if res.group_cost is not None:
+        out["group_cost"] = np.asarray(res.group_cost)
+    return out
 
 
 def same(label: str, got: dict, want: dict) -> None:
@@ -268,6 +281,39 @@ def long_trace_phase(seed: int, mesh) -> None:
     same(f"stream/long-A1 (T={LONG_T}, N={LONG_N})", got, want)
 
 
+def typed_phase(seed: int, mesh) -> None:
+    """AQ-rand on the four-generation fleet: the streaming kernel equals the
+    scan route exactly on ``x``, ``level_cost`` and ``group_cost``."""
+    import jax
+    import numpy as np
+
+    from repro.core import (
+        CostModel,
+        PolicySpec,
+        ProvisionSpec,
+        ServerGroup,
+        Workload,
+        provision,
+        provision_stream,
+    )
+
+    costs = CostModel.from_groups(*(ServerGroup(name, n, P=p) for name, n, p in TYPED4))
+    n_levels = sum(costs.group_sizes)
+    demand = msr_demand(seed, T_SLOTS, TYPED4_MEAN_JOBS, n_levels)
+    spec = ProvisionSpec(costs=costs, workload=Workload(demand=demand),
+                         policy=PolicySpec("AQ-rand", key=jax.random.key(seed)),
+                         n_levels=n_levels)
+    want = host(observe("scan/typed4-AQ-rand", lambda: provision(spec)))
+    s = dataclasses.replace(spec, mesh=mesh)
+    got = pallas_run("stream/typed4-AQ-rand", lambda: provision_stream(s))
+    for k in ("x", "level_cost", "group_cost"):
+        check(np.array_equal(got[k], want[k]),
+              f"stream/typed4-AQ-rand: {k} differs from the scan route")
+    print(f"  stream/typed4-AQ-rand (N={n_levels}, groups {costs.group_sizes}): "
+          f"equals the scan route bit for bit, group_cost {want['group_cost']}",
+          flush=True)
+
+
 def stepper_phase(demand, ref: dict) -> None:
     """``advance()`` at chunk sizes 1, 64, 1024: a warm-up pass, then a
     second pass that may add no compiles; the committed schedule equals
@@ -319,6 +365,7 @@ def smoke(seed: int, devices, chips: int) -> None:
     stream_phase(sp, ref, mesh, "")
     long_trace_phase(seed, mesh)
     stepper_phase(demand, ref)
+    typed_phase(seed, mesh)
 
 
 def main(argv=None) -> int:

@@ -706,6 +706,28 @@ def _group_layout(n_levels, group_sizes, size):
     return route, sel, n_layout
 
 
+@functools.lru_cache(maxsize=64)
+def _layout_lanes(n_levels, group_sizes, size):
+    """(storage lanes, pad lanes) of :func:`_group_layout`'s layout: static
+    per (fleet, mesh size), so the host computes it once."""
+    n_layout = _group_layout(n_levels, group_sizes, size)[2]
+    return n_layout, n_layout - int(n_levels)
+
+
+def _wait_table_bytes(policy, n_windows, n_traces, n_slots, n_layout):
+    """Bytes of the time-varying (K, T, n_layout) float32 threshold table the
+    sharded bodies build for the kernels: K = W·B for A2/A3 (one per window
+    and trace), B for the window-free AQ-rand, 0 where the thresholds are
+    constant rows."""
+    if policy in RANDOMIZED:
+        rows = n_windows * n_traces
+    elif policy == "AQ-rand":
+        rows = n_traces
+    else:
+        rows = 0
+    return rows * n_slots * n_layout * 4
+
+
 @functools.partial(jax.jit, static_argnames=(
     "mesh", "axis", "n_levels", "max_h", "h_unroll", "policy", "use_pallas",
     "group_sizes", "record"))

@@ -362,16 +362,17 @@ def _result(spec: ProvisionSpec, pr: dict, out: dict, decisions,
     level_cost = out["energy"] + out["on_cost"] + out["off_cost"]
     defer = pr["defer"]
     queue = {} if defer is None else defer.metrics(pr["arrivals"], out["x"])
+    group_cost = None
+    if spec.costs.group_sizes is not None:
+        with get_telemetry().span("provision/finish/group_cost"):
+            group_cost = spec.costs.group_reduce(level_cost)
     return ProvisionResult(
         x=out["x"],
         cost=level_cost.sum(axis=-1),
         energy=out["energy"].sum(axis=-1),
         toggle_cost=(out["on_cost"] + out["off_cost"]).sum(axis=-1),
         level_cost=level_cost,
-        group_cost=(
-            None if spec.costs.group_sizes is None
-            else spec.costs.group_reduce(level_cost)
-        ),
+        group_cost=group_cost,
         backlog=queue.get("backlog"),
         max_delay=queue.get("max_delay"),
         p99_delay=queue.get("p99_delay"),
@@ -380,6 +381,23 @@ def _result(spec: ProvisionSpec, pr: dict, out: dict, decisions,
         decisions=decisions,
         decision_counts=counts,
     )
+
+
+def _layout_gauges(tel, spec: ProvisionSpec, pr: dict, policy: str) -> None:
+    """On the ``mesh=`` route, its storage layout and wait table as gauges
+    on a live registry: ``provision/layout_lanes`` and
+    ``provision/layout_pad_lanes`` (the group-aligned lanes and how many
+    of them are pad), ``provision/wait_table_bytes`` (the time-varying
+    threshold table the kernel reads; 0 for constant thresholds)."""
+    if spec.mesh is None or not tel.enabled:
+        return
+    n_layout, pad = _engine._layout_lanes(
+        pr["n_levels"], spec.costs.group_sizes, spec.mesh.shape[spec.mesh_axis])
+    B, T = pr["ab"].shape
+    tel.gauge("provision/layout_lanes", n_layout)
+    tel.gauge("provision/layout_pad_lanes", pad)
+    tel.gauge("provision/wait_table_bytes", _engine._wait_table_bytes(
+        policy, pr["windows"].shape[0], B, T, n_layout))
 
 
 def provision(spec: ProvisionSpec, *, record_decisions: bool = False) -> ProvisionResult:
@@ -414,6 +432,7 @@ def provision(spec: ProvisionSpec, *, record_decisions: bool = False) -> Provisi
             pr = _prepare(spec, pol)
         n_levels = pr["n_levels"]
         outer.set(n_levels=n_levels)
+        _layout_gauges(tel, spec, pr, pol.name)
         engine_in = (pr["ab"], pr["predb"], pr["windows"], pr["delta_lv"],
                      pr["P_lv"], pr["bon_lv"], pr["boff_lv"])
         with tel.span("provision/dispatch"):
@@ -477,9 +496,12 @@ def provision_stream(
     online policy: the carry preserves the engine state across tiles, the
     peek reads into the next tile so chunking never truncates the window,
     and the randomized policies consume the same absolute-slot wait draws
-    (CRN parity; their (T, N) uniform tables are the one O(T) allocation
-    the streaming path keeps — docs/provisioning_engine.md "Streaming &
-    long traces").
+    (CRN parity; their (T, N) uniform tables, and on the ``mesh=`` route
+    the (K, T, N) threshold table the kernel reads, are the O(T)
+    allocations the streaming path keeps — the live registry's
+    ``provision/wait_table_bytes`` gauge reports the threshold table's size
+    on every ``mesh=`` call; docs/provisioning_engine.md "Streaming & long
+    traces").
 
     Two deliberate differences: ``offline`` is rejected (the hindsight
     optimum is a closed form over the whole trace — there is nothing to
@@ -510,6 +532,7 @@ def provision_stream(
             t_chunk = DEFAULT_T_CHUNK
         t_chunk = int(min(max(int(t_chunk), 1), max(T, 1)))
         outer.set(n_levels=n_levels, t_chunk=t_chunk)
+        _layout_gauges(tel, spec, pr, pol.name)
         engine_in = (pr["ab"], pr["predb"], pr["windows"], pr["delta_lv"],
                      pr["P_lv"], pr["bon_lv"], pr["boff_lv"])
         with tel.span("provision/dispatch"):

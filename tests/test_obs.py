@@ -391,6 +391,44 @@ def test_planning_entries_span_their_phases(entry):
     assert total <= outer["dur"]
 
 
+#: four server generations of one Google cell (12,391 levels): 99 blocks of
+#: 128 group-pure lanes, 281 of them pad
+TYPED4 = (6732, 3863, 1001, 795)
+
+
+@pytest.mark.parametrize("entry", ["provision", "provision_stream"])
+def test_mesh_route_gauges_its_layout_and_wait_table(entry):
+    """Every ``mesh=`` call sets the layout and wait-table gauges on a live
+    registry; a typed fleet opens ``provision/finish/group_cost`` inside
+    ``provision/finish``, an untyped one does not."""
+    from repro.core import provision_stream
+
+    fn = {"provision": provision, "provision_stream": provision_stream}[entry]
+    T = 4
+    costs = CostModel.from_groups(*(ServerGroup(f"type{k}", n, P=p) for k, (n, p)
+                                    in enumerate(zip(TYPED4, (1.0, 1.25, 1.5, 2.0)))))
+    a = jnp.asarray([12_000, 300, 9_000, 0], jnp.int32)
+    mesh = jax.make_mesh((1,), ("data",))
+    for policy, key, table in (("AQ-rand", jax.random.key(3), T * 12_672 * 4),
+                               ("AQ-det", None, 0)):
+        spec = ProvisionSpec(costs=costs, workload=Workload(demand=a),
+                             policy=PolicySpec(policy, key=key), mesh=mesh,
+                             use_pallas=False)
+        with telemetry_session() as tel:
+            jax.block_until_ready(fn(spec).x)
+        assert tel.gauge_value("provision/layout_lanes") == 12_672
+        assert tel.gauge_value("provision/layout_pad_lanes") == 281
+        assert tel.gauge_value("provision/wait_table_bytes") == table
+        (ev,) = _events(tel, "provision/finish/group_cost")
+        assert ev["args"]["parent"] == "provision/finish"
+    with telemetry_session() as tel:
+        jax.block_until_ready(fn(_spec(np.arange(T), 16, mesh=mesh, use_pallas=False)).x)
+    assert tel.gauge_value("provision/layout_lanes") == 16       # A1, untyped
+    assert tel.gauge_value("provision/layout_pad_lanes") == 0
+    assert tel.gauge_value("provision/wait_table_bytes") == 0
+    assert not _events(tel, "provision/finish/group_cost")
+
+
 def test_advance_spans_its_phases_and_times_the_whole_tick():
     from repro.serving import FleetProvisioner
 

@@ -108,3 +108,30 @@ def test_sharded_stream_grid_compiles_on_four_chips(topo, aot):
     text = lowered.compile().as_text()
     assert "tpu_custom_call" in text
     assert "all-reduce" in text            # x(t) is a psum over the shards
+
+
+def test_sharded_stream_grid_compiles_typed4_aqrand_on_one_chip(topo, aot):
+    """The typed fleet's route on one chip: four server generations of one
+    Google cell (12,391 levels) in the group-aligned layout, 99 blocks of
+    128 lanes, and AQ-rand's keyed (1, T, 12,672) threshold table, built
+    from the draws in the same program, over a week of ten-minute slots."""
+    from repro.core.jax_provision import _sharded_stream_grid
+
+    groups, t_week = (6732, 3863, 1001, 795), 1_008
+    n = sum(groups)
+    mesh = Mesh(np.asarray(topo.devices[:1]), ("data",))
+    rep = NamedSharding(mesh, P())
+
+    def s(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=rep)
+
+    keys = jax.eval_shape(lambda: jax.random.split(jax.random.key(0), 1))
+    lowered = _sharded_stream_grid.lower(
+        s((1, t_week), jnp.int32), s((1, 1, t_week), jnp.int32), s((1,), jnp.int32),
+        s((n,), jnp.float32), s((n,), jnp.float32), s((n,), jnp.float32),
+        s((n,), jnp.float32), s(keys.shape, keys.dtype),
+        mesh=mesh, axis="data", n_levels=n, max_h=DELTA, h_unroll=0,
+        policy="AQ-rand", use_pallas=True, group_sizes=groups, t_chunk=512,
+    )
+    text = lowered.compile().as_text()
+    assert "%provision_scan_stream" in text
