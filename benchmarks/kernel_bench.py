@@ -211,12 +211,14 @@ def provision_stream_long(rows: list[str], *, full: bool = False) -> None:
 
     One row per (T, N, layout): ``us_per_call`` plus ``decisions_per_s``,
     per-slot latency ``slot_ns`` and the working-set estimates
-    ``mem_stream_bytes`` (2 trace tiles x double buffer + per-level carry)
-    vs ``mem_monolithic_bytes`` (the prefetch-all layout's whole-trace
-    residency) — O(T_chunk) against O(T).  The overlapping size runs both
-    kernels and asserts bit-identical replica counts before timing.
+    ``mem_stream_bytes`` (2 trace tiles x double buffer, the (t_chunk, BN)
+    on-tile + per-level carry) vs ``mem_monolithic_bytes`` (the
+    prefetch-all layout's whole-trace residency and its on-matrix block)
+    — O(T_chunk) against O(T).  The overlapping size runs both kernels and
+    asserts bit-identical replica counts before timing.
     """
     from repro.kernels.provision_scan import (
+        DEFAULT_BN,
         provision_scan_grid,
         provision_scan_stream,
     )
@@ -233,8 +235,11 @@ def provision_stream_long(rows: list[str], *, full: bool = False) -> None:
 
     def mem(T, n, tc):
         # demand + predicted rows (int32): tiles x double buffer streaming,
-        # whole-trace residency monolithic; carry is per-level either way
-        return 2 * 2 * tc * 4 + 3 * n * 4, 2 * T * 4
+        # plus the f32 on-tile the x partials are summed from; whole-trace
+        # residency and the (T, BN) on-matrix block monolithic; carry is
+        # per-level either way
+        return (2 * 2 * tc * 4 + tc * DEFAULT_BN * 4 + 3 * n * 4,
+                2 * T * 4 + T * DEFAULT_BN * 4)
 
     def stream_fn(a, thr, tc):
         return jax.jit(lambda a: provision_scan_stream(

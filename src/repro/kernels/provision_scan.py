@@ -58,7 +58,7 @@ Two kernels share the slot semantics:
     tiles with double-buffered async copies into SMEM/VMEM scratch; the
     per-level ``(run-length, on-bit, wait)`` state is carried across tiles
     in registers and returned to the caller, so a call's working set is
-    O(t_chunk + BN) regardless of T and consecutive calls chain bit-exactly
+    O(t_chunk · BN) regardless of T and consecutive calls chain bit-exactly
     via the carry (see docs/provisioning_engine.md "Streaming & long
     traces").
 """
@@ -308,9 +308,10 @@ def _stream_scan_kernel(
     time_varying: bool, record: bool,
 ):
     if time_varying:
-        a_scr, p_scr, x_scr, thr_scr, a_sem, p_sem, x_sem, thr_sem = scratch
+        (a_scr, p_scr, x_scr, on_scr, thr_scr,
+         a_sem, p_sem, x_sem, thr_sem) = scratch
     else:
-        a_scr, p_scr, x_scr, a_sem, p_sem, x_sem = scratch
+        a_scr, p_scr, x_scr, on_scr, a_sem, p_sem, x_sem = scratch
     g = pl.program_id(0)
     j = pl.program_id(1)
     b = cb_ref[g]
@@ -382,7 +383,7 @@ def _stream_scan_kernel(
             thr_dma(slot, i).wait()
 
         # the x slot is reused every other tile: its previous DMA-out must
-        # have landed before this tile's slot loop overwrites the buffer
+        # have landed before this tile's reduction overwrites the buffer
         @pl.when(i >= 2)
         def _():
             x_dma(slot, i - 2).wait()
@@ -425,7 +426,8 @@ def _stream_scan_kernel(
             on_f = on_n & ~off_now
             r_n = jnp.where(off_now, 0.0, r_n)
             ok = on_f & lane_ok
-            x_scr[slot, 0, tl] = jnp.sum(ok.astype(jnp.int32))
+            # one vector store per slot; the lane sum waits for the tile
+            on_scr[pl.ds(tl, 1), :] = ok.astype(jnp.float32)
 
             def acc(tot, inc):
                 return jnp.where(valid, tot + inc.astype(jnp.int32), tot)
@@ -448,6 +450,14 @@ def _stream_scan_kernel(
             return out
 
         st = jax.lax.fori_loop(0, t_chunk, slot_body, st)
+        # x partials of the whole tile in one MXU product: ones (8, BN)
+        # against the on-tile's lanes gives (8, t_chunk) rows of lane
+        # sums, exact (0/1 terms, at most BN of them, f32 accumulation)
+        xs = jax.lax.dot_general(
+            jnp.ones((8, bn), jnp.float32), on_scr[...],
+            (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32,
+        )
+        x_scr[slot] = xs[0:1, :].astype(jnp.int32)
         x_dma(slot, i).start()
         return st
 
@@ -485,13 +495,15 @@ def provision_scan_stream(
     record: bool = False,
     carry: dict | None = None,  # {"r","on","wait"} each (G, N) — None = fresh
 ) -> tuple[jax.Array, dict, dict]:
-    """Streaming provisioning scan: O(t_chunk + levels) working set, any T.
+    """Streaming provisioning scan: O(t_chunk · BN) working set, any T.
 
     The same per-cell slot semantics as :func:`provision_scan_grid`, but
     the demand/predicted rows (and the (K, T, N) wait tables of the
     randomized policies) stay in HBM (``pl.ANY``) and are streamed in
-    ``t_chunk``-slot tiles with double-buffered async copies; x(t) partials
-    are DMA'd back out per tile.  Instead of the on-matrix, the kernel
+    ``t_chunk``-slot tiles with double-buffered async copies; each slot
+    stores its masked on row into a ``(t_chunk, BN)`` VMEM tile, which is
+    lane-summed once per tile (one MXU product) into x(t) partials that
+    are DMA'd back out.  Instead of the on-matrix, the kernel
     returns what the engine actually reduces it to:
 
     - ``x`` (G, T) int32 — on-lane count per slot (lanes masked to
@@ -596,7 +608,8 @@ def provision_scan_stream(
     scratch = [
         pltpu.SMEM((2, 1, t_chunk), jnp.int32),            # a tiles
         pltpu.SMEM((2, 1, t_chunk + p_ext), jnp.int32),    # p tiles (+ lookahead)
-        pltpu.SMEM((2, 1, t_chunk), jnp.int32),            # x partials out
+        pltpu.VMEM((2, 1, t_chunk), jnp.int32),            # x partials out
+        pltpu.VMEM((t_chunk, bn), jnp.float32),            # on rows of a tile
     ]
     if time_varying:
         scratch.append(pltpu.VMEM((2, t_chunk, bn), jnp.float32))

@@ -162,6 +162,69 @@ def test_kernel_stream_carry_chains_across_calls(demand):
     assert (got == np.asarray(x_full)).all()
 
 
+# (T, t_chunk, n lanes, n_levels, time-varying thresholds, record): the
+# streaming kernel sums each tile's on rows once, so the cases put that
+# sum at a tile's edges — one tile or several, a ragged pad tail or none,
+# pad lanes of a partial block and real lanes cut off by n_levels
+STREAM_TILE_CASES = {
+    "one-tile": (32, 64, 18, 18, False, False),
+    "three-tiles-exact-time_varying": (96, 32, 150, 140, True, False),
+    "ragged-tail-record": (100, 32, 150, 140, False, True),
+    "ragged-tail-time_varying-record": (77, 16, 130, 129, True, True),
+    "one-tile-pad-lanes-time_varying-record": (40, 512, 200, 170, True, True),
+}
+
+
+@pytest.mark.parametrize("case", sorted(STREAM_TILE_CASES))
+def test_kernel_stream_tile_sums_match_grid_on_matrix(case):
+    """provision_scan_stream's x and per-lane totals equal the grid
+    kernel's on-matrix summed over lanes and over slots, bit for bit."""
+    from repro.kernels.provision_scan import (
+        provision_scan_grid,
+        provision_scan_stream,
+    )
+
+    T_, t_chunk, n, n_levels, time_varying, record = STREAM_TILE_CASES[case]
+    horizon = 3
+    rng = np.random.default_rng(T_ * 1009 + n)
+    traces = jnp.asarray(rng.integers(0, n + 8, size=(2, T_)), jnp.int32)
+    predicted = jnp.asarray(rng.integers(0, n + 8, size=(2, T_)), jnp.int32)
+    thr = jnp.asarray(rng.uniform(0.0, 6.0, size=(2, T_ if time_varying else 1, n)),
+                      jnp.float32)
+    hor = jnp.asarray(rng.uniform(0.0, horizon + 0.5, size=(2, n)), jnp.float32)
+    cells = (jnp.asarray([0, 1], jnp.int32), jnp.asarray([1, 0], jnp.int32),
+             jnp.asarray([0, 1], jnp.int32), jnp.asarray([1, 0], jnp.int32))
+
+    x, acc, _ = provision_scan_stream(
+        traces, predicted, thr, *cells, horizon=horizon, t_chunk=t_chunk,
+        n_levels=n_levels, level_horizon=hor, record=record)
+    grid = provision_scan_grid(
+        traces, predicted, thr, *cells, delta=horizon, horizon=horizon,
+        level_horizon=hor, record=record)
+    ons, counts = grid if record else (grid, None)
+    ons = np.asarray(ons)[:, :, :n_levels].astype(np.int32)   # (G, T, lanes)
+
+    assert np.asarray(x).dtype == np.int32
+    assert (np.asarray(x) == ons.sum(-1)).all()
+    # totals over the kernel's lanes: the n_levels cut-off lanes read 0;
+    # edges against the virtual x(0) = a(0) boundary (no toggle at t = 0)
+    want = {
+        "run": ons.sum(1),
+        "up": (ons[:, 1:] & (1 - ons[:, :-1])).sum(1),
+        "down": (ons[:, :-1] & (1 - ons[:, 1:])).sum(1),
+    }
+    if record:
+        names = ("demand_rise", "wait_expired", "peek_fired", "toggle_off")
+        want.update({k: np.asarray(counts)[:, i, :n_levels]
+                     for i, k in enumerate(names)})
+    assert sorted(acc) == sorted(want)
+    for k, v in want.items():
+        got = np.asarray(acc[k])
+        assert got.shape == (2, n), k
+        assert (got[:, :n_levels] == v).all(), k
+        assert (got[:, n_levels:] == 0).all(), k
+
+
 def test_interpret_env_override_and_telemetry_gauge(monkeypatch):
     from repro.kernels.provision_scan import _resolve_interpret
     from repro.obs.telemetry import telemetry_session

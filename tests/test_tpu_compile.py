@@ -64,10 +64,21 @@ def _kernel_args(sharding, time_varying):
     )
 
 
-@pytest.mark.parametrize("time_varying", [False, True],
-                         ids=["constant", "time_varying"])
-@pytest.mark.parametrize("kernel", ["grid", "stream"])
-def test_kernel_compiles_for_v5e(topo, aot, kernel, time_varying):
+_KERNEL_CASES = {
+    "grid-constant": ("grid", False, False),
+    "grid-time_varying": ("grid", True, False),
+    "stream-constant": ("stream", False, False),
+    "stream-time_varying": ("stream", True, False),
+    # record=True carries seven per-lane accumulators through the slot loop
+    # beside the on-tile store; the tile's MXU lane sum must fit there too
+    "stream-constant-record": ("stream", False, True),
+    "stream-time_varying-record": ("stream", True, True),
+}
+
+
+@pytest.mark.parametrize(("kernel", "time_varying", "record"),
+                         list(_KERNEL_CASES.values()), ids=list(_KERNEL_CASES))
+def test_kernel_compiles_for_v5e(topo, aot, kernel, time_varying, record):
     from repro.kernels.provision_scan import (
         provision_scan_grid,
         provision_scan_stream,
@@ -75,7 +86,7 @@ def test_kernel_compiles_for_v5e(topo, aot, kernel, time_varying):
 
     def run(a, p, m, ct, cp, cthr, chor, hor, routes):
         kw = dict(horizon=DELTA, routes=routes, level_horizon=hor,
-                  interpret=False)
+                  interpret=False, record=record)
         if kernel == "grid":
             return provision_scan_grid(a, p, m, ct, cp, cthr, chor,
                                        delta=DELTA, **kw)
