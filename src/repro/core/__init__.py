@@ -1,19 +1,16 @@
 """The paper's contribution: power-proportional dynamic provisioning.
 
 Public API:
-  * Declarative provisioning: ``provision(ProvisionSpec(...))`` with
-    ``CostModel`` (scalar or per-level), ``Workload``, ``PolicySpec``,
-    ``PredictionNoise`` — returns a ``ProvisionResult``.
+  * Declarative provisioning: ``provision(ProvisionSpec(...))`` (and
+    ``provision_stream`` for long traces) with ``CostModel`` (scalar or
+    per-level), ``Workload``, ``PolicySpec``, ``PredictionNoise`` — returns
+    a ``ProvisionResult``.
   * Brick (continuous-time) model: ``BrickTrace``, ``simulate`` (online),
     ``a0_schedule``/``a0_cost``/``optimal_schedule_constructed`` (offline),
     ``critical_segments``.
   * Fluid (discrete-time) model: ``fluid_cost``, ``fluid_scan``.
   * Policies: ``A1Deterministic``, ``A2Randomized``, ``A3Randomized``.
   * Validation: ``dp_optimal_cost``.
-
-The loose-kwargs ``provision_schedule``/``provision_sweep[_costs]``/
-``provision_cost``/``provision_schedule_sharded`` functions are deprecated
-wrappers around ``provision``.
 """
 from ..deferral import DeferralSpec
 from .costs import PAPER_COSTS, CostModel, ServerGroup, schedule_cost
@@ -24,11 +21,6 @@ from .jax_provision import (
     POLICIES,
     RANDOMIZED as RANDOMIZED_POLICIES,
     on_matrix_cost,
-    provision_cost,
-    provision_schedule,
-    provision_schedule_sharded,
-    provision_sweep,
-    provision_sweep_costs,
 )
 from .provision import (
     PolicySpec,
@@ -83,11 +75,6 @@ __all__ = [
     "provision",
     "provision_stream",
     "on_matrix_cost",
-    "provision_cost",
-    "provision_schedule",
-    "provision_schedule_sharded",
-    "provision_sweep",
-    "provision_sweep_costs",
     "a0_cost",
     "a0_schedule",
     "optimal_cost",

@@ -30,12 +30,25 @@ independent per-level computation, so the whole fleet is one vectorized
     and prediction traces indexed per cell — bit-exact against the
     ``lax.scan`` programs above (common random numbers on every axis).
 
-The public entrypoint is :func:`repro.core.provision.provision`, driven by a
-declarative :class:`~repro.core.provision.ProvisionSpec`.  The loose-kwargs
-functions that predate it (``provision_schedule``, ``provision_sweep``,
-``provision_sweep_costs``, ``provision_cost``,
-``provision_schedule_sharded``) remain as thin deprecated wrappers that
-forward to the same engine.
+The public entrypoints are :func:`repro.core.provision.provision` and
+:func:`repro.core.provision.provision_stream`, driven by a declarative
+:class:`~repro.core.provision.ProvisionSpec`.  Each route keeps its own
+jitted body (``_run``/``_run_noise_sweep`` and ``_run_stream``/
+``_run_stream_noise`` on the lax.scan route, ``_sharded_grid`` and
+``_sharded_stream_grid`` on the ``mesh=`` route); each planning decision
+they share is made in one helper:
+
+  * which policies draw waits, at which shape and which α —
+    :func:`_wait_draws` and :func:`_draw_waits` (and :func:`_n_wait_tables`
+    for how many tables the mesh route holds);
+  * the (window × trace) sweep of the lax.scan bodies, window-free
+    policies broadcast — :func:`_scan_sweep`;
+  * the mesh route's storage layout, padded per-level rows, thresholds,
+    horizon rows and (S, W, B) cell maps — :func:`_grid_inputs`; its
+    ``shard_map`` specs and compaction back to level order —
+    :func:`_grid_map`;
+  * the mesh route's host prelude (policy and key checks, the static peek
+    unroll) — :func:`_sharded_run`.
 
 Semantics mirror :func:`repro.core.fluid.fluid_scan` exactly (tested).
 
@@ -52,12 +65,11 @@ from __future__ import annotations
 
 import functools
 import math
-import warnings
 
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.sharding import Mesh, PartitionSpec as P
+from jax.sharding import PartitionSpec as P
 
 from ..obs import provenance as _prov
 
@@ -120,6 +132,40 @@ def _waits_from_uniforms(policy, u0, u, window, delta):
         p0 = alpha / (E - 1.0 + alpha)
         waits = jnp.where(u0 < p0, 0.0, waits)
     return waits
+
+
+def _wait_draws(policy, keys, T, n_levels):
+    """Per-trace uniform tables ``(u0, u)``, each (B, T, n_levels), for the
+    policies that draw their waits (:data:`KEYED`); None for the rest.
+
+    Drawn per trace at ``n_levels`` — never at the sharded layout's width —
+    so the (trace, key) -> schedule contract holds regardless of mesh size
+    or group padding; every window reuses them (common random numbers).
+    """
+    if policy not in KEYED:
+        return None
+    return jax.vmap(lambda k: _uniforms(k, T, n_levels))(keys)
+
+
+def _draw_waits(policy, draws, window, delta):
+    """One trace's (T, N) wait thresholds from its slice of
+    :func:`_wait_draws` (None passes through).  The transform sees the
+    sweep's ``window`` for A2/A3 and window 0 for the window-free AQ-rand,
+    whose α it pins to 0."""
+    if draws is None:
+        return None
+    u0, u = draws
+    return _waits_from_uniforms(
+        policy, u0, u, window if policy in RANDOMIZED else 0, delta)
+
+
+def _n_wait_tables(policy, n_windows, n_traces):
+    """How many (T, N) threshold tables the sharded bodies hold for a
+    (W, B) sweep: one per window and trace for A2/A3, one per trace for
+    the window-free AQ-rand, none where the thresholds are constant rows."""
+    if policy not in KEYED:
+        return 0
+    return n_traces * (1 if policy in WINDOW_FREE else n_windows)
 
 
 # ---------------------------------------------------------------------------
@@ -358,8 +404,8 @@ def _level_schedule(a, n_levels, delta, window, policy, predicted=None, key=None
     waits = None
     if policy in KEYED:
         _require_key(policy, key)
-        u0, u = _uniforms(key, a.shape[0], n_levels)
-        waits = _waits_from_uniforms(policy, u0, u, window, delta)
+        waits = _draw_waits(policy, _uniforms(key, a.shape[0], n_levels),
+                            window, delta)
     levels = jnp.arange(n_levels)
     return _on_matrix_scan(
         a, pred, levels, delta=delta, max_h=max_h, window=window, policy=policy,
@@ -408,8 +454,33 @@ def on_matrix_cost(a, on_matrix, costs):
 
 
 # ---------------------------------------------------------------------------
-# The one engine body: (windows × traces × levels) in a single program
+# The lax.scan route: (windows × traces × levels) in a single program
 # ---------------------------------------------------------------------------
+
+def _scan_sweep(cell, ab, predb, windows, keys, delta, *, n_levels, policy):
+    """The (W, B) sweep of the lax.scan bodies :func:`_run` and
+    :func:`_run_stream`: ``cell(ai, pi, w, waits)`` — one (window, trace)
+    cell's dict of leaves — vmapped over traces and windows, with each
+    trace's waits from :func:`_wait_draws` (common random numbers across
+    the sweep).  Returns each leaf (W, B, ...).
+    """
+    draws = _wait_draws(policy, keys, ab.shape[1], n_levels)
+
+    def over_traces(w):
+        return jax.vmap(
+            lambda ai, pi, d: cell(ai, pi, w, _draw_waits(policy, d, w, delta))
+        )(ab, predb, draws)
+
+    if policy in WINDOW_FREE:
+        # window-independent policies: compute once, broadcast over the sweep
+        # (AQ-rand draws its per-level waits from the key but never peeks,
+        # so one sample serves the whole sweep too)
+        out = over_traces(0)
+        return jax.tree.map(
+            lambda o: jnp.broadcast_to(o[None], (windows.shape[0],) + o.shape), out
+        )
+    return jax.vmap(over_traces)(windows)                # each leaf (W, B, ...)
+
 
 @functools.partial(jax.jit, static_argnames=("n_levels", "max_h", "policy",
                                              "record"))
@@ -430,65 +501,24 @@ def _run(ab, predb, windows, delta, P_lv, beta_on_lv, beta_off_lv, keys, *,
     """
     if record and policy == "offline":
         raise ValueError("record=True: offline has no slot scan to record")
-    B, T = ab.shape
     levels = jnp.arange(n_levels)
 
-    def reduce(ai, ons, codes=None):
+    def cell(ai, pi, w, waits):
+        if policy == "offline":
+            ons, codes = _offline_levels(ai, n_levels, delta), None
+        else:
+            res = _on_matrix_scan(ai, pi, levels, delta=delta, max_h=max_h,
+                                  window=w, policy=policy, waits=waits,
+                                  record=record)
+            ons, codes = res if record else (res, None)
         out = _cost_terms(ai, ons, P_lv, beta_on_lv, beta_off_lv)
         out["x"] = ons.sum(axis=1).astype(jnp.int32)
         if record:
             out["decisions"] = codes
         return out
 
-    def scan(ai, pi, w, waits):
-        res = _on_matrix_scan(ai, pi, levels, delta=delta, max_h=max_h,
-                              window=w, policy=policy, waits=waits,
-                              record=record)
-        return res if record else (res, None)
-
-    if policy in WINDOW_FREE:
-        # window-independent policies: compute once, broadcast over the sweep
-        # (AQ-rand draws its per-level waits from the key but never peeks,
-        # so one sample serves the whole sweep too)
-        if policy == "AQ-rand":
-            u0, u = jax.vmap(lambda k: _uniforms(k, T, n_levels))(keys)
-        else:
-            u0 = u = jnp.zeros((B, 0, 0))
-
-        def one(ai, pi, u0i, ui):
-            if policy == "offline":
-                return reduce(ai, _offline_levels(ai, n_levels, delta))
-            waits = (
-                _waits_from_uniforms(policy, u0i, ui, 0, delta)
-                if policy == "AQ-rand"
-                else None
-            )
-            ons, codes = scan(ai, pi, 0, waits)
-            return reduce(ai, ons, codes)
-
-        out = jax.vmap(one)(ab, predb, u0, u)
-        return jax.tree.map(
-            lambda o: jnp.broadcast_to(o[None], (windows.shape[0],) + o.shape), out
-        )
-
-    if policy in RANDOMIZED:
-        u0, u = jax.vmap(lambda k: _uniforms(k, T, n_levels))(keys)   # (B, T, N)
-    else:
-        u0 = u = jnp.zeros((B, 0, 0))
-
-    def per_window(w):
-        def per_trace(ai, pi, u0i, ui):
-            waits = (
-                _waits_from_uniforms(policy, u0i, ui, w, delta)
-                if policy in RANDOMIZED
-                else None
-            )
-            ons, codes = scan(ai, pi, w, waits)
-            return reduce(ai, ons, codes)
-
-        return jax.vmap(per_trace)(ab, predb, u0, u)
-
-    return jax.vmap(per_window)(windows)                 # each leaf (W, B, ...)
+    return _scan_sweep(cell, ab, predb, windows, keys, delta,
+                       n_levels=n_levels, policy=policy)
 
 
 @functools.partial(jax.jit, static_argnames=("n_levels", "max_h", "policy",
@@ -537,10 +567,9 @@ def _run_stream(ab, predb, windows, delta, P_lv, beta_on_lv, beta_off_lv, keys,
             "offline is closed-form over the full trace; the streaming engine "
             "is online-only — use provision() for offline"
         )
-    B, T = ab.shape
     levels = jnp.arange(n_levels)
 
-    def one_cell(ai, pi, w, waits):
+    def cell(ai, pi, w, waits):
         x, t_, _ = _stream_cell(
             ai, pi, levels, delta=delta, max_h=max_h, window=w, policy=policy,
             waits=waits, t_chunk=t_chunk, record=record,
@@ -557,42 +586,8 @@ def _run_stream(ab, predb, windows, delta, P_lv, beta_on_lv, beta_off_lv, keys,
             )                                                    # (4, N)
         return out
 
-    if policy in WINDOW_FREE:
-        if policy == "AQ-rand":
-            u0, u = jax.vmap(lambda k: _uniforms(k, T, n_levels))(keys)
-        else:
-            u0 = u = jnp.zeros((B, 0, 0))
-
-        def one(ai, pi, u0i, ui):
-            waits = (
-                _waits_from_uniforms(policy, u0i, ui, 0, delta)
-                if policy == "AQ-rand"
-                else None
-            )
-            return one_cell(ai, pi, 0, waits)
-
-        out = jax.vmap(one)(ab, predb, u0, u)
-        return jax.tree.map(
-            lambda o: jnp.broadcast_to(o[None], (windows.shape[0],) + o.shape), out
-        )
-
-    if policy in RANDOMIZED:
-        u0, u = jax.vmap(lambda k: _uniforms(k, T, n_levels))(keys)   # (B, T, N)
-    else:
-        u0 = u = jnp.zeros((B, 0, 0))
-
-    def per_window(w):
-        def per_trace(ai, pi, u0i, ui):
-            waits = (
-                _waits_from_uniforms(policy, u0i, ui, w, delta)
-                if policy in RANDOMIZED
-                else None
-            )
-            return one_cell(ai, pi, w, waits)
-
-        return jax.vmap(per_trace)(ab, predb, u0, u)
-
-    return jax.vmap(per_window)(windows)                 # each leaf (W, B, ...)
+    return _scan_sweep(cell, ab, predb, windows, keys, delta,
+                       n_levels=n_levels, policy=policy)
 
 
 @functools.partial(jax.jit, static_argnames=("n_levels", "max_h", "policy",
@@ -620,22 +615,24 @@ def _run_stream_noise(ab, predb, windows, delta, P_lv, beta_on_lv, beta_off_lv,
 
 def _sharded_run(mesh, axis, ab, predb, windows, delta, P_lv, beta_on_lv,
                  beta_off_lv, *, n_levels, max_h, policy, keys=None,
-                 use_pallas=True, group_sizes=None, record=False):
+                 use_pallas=True, group_sizes=None, t_chunk=None, record=False):
     """Level-sharded engine over the full (S, W, B) sweep grid.
 
     ``ab``: (B, T) demand; ``predb``: (S, B, T) predicted traces (S = 1
     without a noise sweep); ``windows``: (W,) concrete window values;
     ``keys``: (B,) per-trace keys for the randomized policies.  Returns the
     same dict as :func:`_run_noise_sweep` — leaves shaped (S, W, B, ...) —
-    computed through the fused Pallas grid scan
-    (:func:`repro.kernels.provision_scan.provision_scan_grid`): one program
-    per ((s, w, b) cell, level block), levels sharded over ``axis``.
+    with levels sharded over ``axis``: through :func:`_sharded_grid` (the
+    fused Pallas grid scan, one program per ((s, w, b) cell, level block))
+    when ``t_chunk`` is None, else through :func:`_sharded_stream_grid`
+    (the streaming kernel in ``t_chunk``-slot tiles; ``provision_stream``
+    clamps ``t_chunk`` to the trace).
 
     Bit-exact against the lax.scan programs: the wait tables are the same
     per-trace uniform draws transformed per window (common random numbers
-    across both sweep axes — noise cells share draws outright).  The thin
-    python wrapper only concretizes the static unroll bound; the body is
-    :func:`_sharded_grid`, a separate jitted entrypoint so the fleet path's
+    across both sweep axes — noise cells share draws outright).  This host
+    prelude checks the policy and key and concretizes the static unroll
+    bound; the bodies are separate jitted entrypoints so the fleet path's
     compiles land in a countable cache (watched by the eval harness and the
     benchmark smoke gates alongside ``_run``/``_run_noise_sweep``).
     """
@@ -660,13 +657,14 @@ def _sharded_run(mesh, axis, ab, predb, windows, delta, P_lv, beta_on_lv,
             # a wider unroll only costs a few masked compares
             w_max = max_h
         h_unroll = int(min(w_max + 1, max_h))
-    return _sharded_grid(
-        jnp.asarray(ab), jnp.asarray(predb), windows, delta, P_lv,
-        beta_on_lv, beta_off_lv, keys,
-        mesh=mesh, axis=axis, n_levels=n_levels, max_h=max_h,
-        h_unroll=h_unroll, policy=policy, use_pallas=use_pallas,
-        group_sizes=group_sizes, record=record,
-    )
+    args = (jnp.asarray(ab), jnp.asarray(predb), windows, delta, P_lv,
+            beta_on_lv, beta_off_lv, keys)
+    kw = dict(mesh=mesh, axis=axis, n_levels=n_levels, max_h=max_h,
+              h_unroll=h_unroll, policy=policy, use_pallas=use_pallas,
+              group_sizes=group_sizes, record=record)
+    if t_chunk is None:
+        return _sharded_grid(*args, **kw)
+    return _sharded_stream_grid(*args, t_chunk=t_chunk, **kw)
 
 
 #: routing id for pad lanes in the sharded level layout: compares false
@@ -714,18 +712,106 @@ def _layout_lanes(n_levels, group_sizes, size):
     return n_layout, n_layout - int(n_levels)
 
 
-def _wait_table_bytes(policy, n_windows, n_traces, n_slots, n_layout):
-    """Bytes of the time-varying (K, T, n_layout) float32 threshold table the
-    sharded bodies build for the kernels: K = W·B for A2/A3 (one per window
-    and trace), B for the window-free AQ-rand, 0 where the thresholds are
-    constant rows."""
-    if policy in RANDOMIZED:
-        rows = n_windows * n_traces
-    elif policy == "AQ-rand":
-        rows = n_traces
+def _grid_inputs(ab, predb, windows, delta, P_lv, beta_on_lv, beta_off_lv,
+                 keys, *, size, n_levels, policy, group_sizes):
+    """The operands both sharded bodies hand :func:`_grid_map`, in the
+    storage layout of :func:`_group_layout` over ``size`` shards.
+
+    Returns ``(operands, sel, per_shard)``.  ``operands``, in
+    :func:`_grid_map`'s order: the demand rows and the (S·B, T) predicted
+    rows; the five (G,) cell maps of cell g = (s, w, b) in row-major order
+    (demand row, predicted row, threshold row, horizon row, window value —
+    the (S, W, B) axis convention of :func:`_run_noise_sweep`); the (K, T
+    or 1, n_layout) thresholds (the waits of :func:`_draw_waits` for the
+    keyed policies, the timer Δ_l for delayedoff/AQ-det, A1's static
+    threshold per window); the (W, n_layout) peek horizons; then Δ, the
+    three cost fields and the routing ids, each one (n_layout,) row.
+    """
+    S, B, T = predb.shape
+    W = windows.shape[0]
+    route_np, sel_np, n_layout = _group_layout(n_levels, group_sizes, size)
+    per_shard = n_layout // size
+    route = jnp.asarray(route_np)
+    sel = jnp.asarray(sel_np)
+
+    def pad_lv(v, fill):
+        # scatter a real (n_levels,) row into the storage layout; pad lanes
+        # take ``fill`` (they are masked out of every output anyway)
+        v = jnp.broadcast_to(jnp.asarray(v, jnp.float32), (n_levels,))
+        return jnp.full((n_layout,), fill, jnp.float32).at[sel].set(v)
+
+    b_real = jnp.broadcast_to(jnp.asarray(delta, jnp.float32), (n_levels,))
+    b = pad_lv(delta, 1.0)          # padded lanes are masked out; Δ irrelevant
+    wf = windows.astype(jnp.float32)
+    draws = _wait_draws(policy, keys, T, n_levels)
+    if draws is not None:
+        def tables(w):                                       # (B, T, N)
+            return jax.vmap(lambda d: _draw_waits(policy, d, w, b_real))(draws)
+
+        # scatter the tables into the layout: one per (window, trace) for
+        # A2/A3, one per trace serving the whole sweep for AQ-rand
+        waits = tables(0) if policy in WINDOW_FREE else jax.vmap(tables)(wf)
+        thresholds = (
+            jnp.zeros(waits.shape[:-1] + (n_layout,), jnp.float32)
+            .at[..., sel].set(waits)
+            .reshape(_n_wait_tables(policy, W, B), T, n_layout)
+        )
+    elif policy in NO_PEEK:
+        thresholds = jnp.broadcast_to(b, (W, n_layout))[:, None, :]  # timer Δ_l
+    else:                                                    # A1 per window
+        thresholds = jnp.maximum(0.0, b[None, :] - wf[:, None] - 1.0)[:, None, :]
+    if policy in NO_PEEK:
+        horizon_wl = jnp.zeros((W, n_layout), jnp.float32)   # no peek
     else:
-        rows = 0
-    return rows * n_slots * n_layout * 4
+        horizon_wl = jnp.minimum(wf[:, None] + 1.0, b[None, :])
+    P_pad = pad_lv(P_lv, 0.0)
+    bon_pad = pad_lv(beta_on_lv, 0.0)
+    boff_pad = pad_lv(beta_off_lv, 0.0)
+
+    s_ix, w_ix, b_ix = jnp.meshgrid(
+        jnp.arange(S), jnp.arange(W), jnp.arange(B), indexing="ij"
+    )
+    cell_trace = b_ix.reshape(-1).astype(jnp.int32)
+    cell_pred = (s_ix * B + b_ix).reshape(-1).astype(jnp.int32)
+    if draws is None:
+        cell_thr = w_ix.reshape(-1).astype(jnp.int32)
+    elif policy in WINDOW_FREE:
+        cell_thr = b_ix.reshape(-1).astype(jnp.int32)        # per-trace tables
+    else:
+        cell_thr = (w_ix * B + b_ix).reshape(-1).astype(jnp.int32)
+    cell_hor = w_ix.reshape(-1).astype(jnp.int32)
+    cell_w = windows[w_ix.reshape(-1)]
+    pred_rows = predb.reshape(S * B, T)
+    operands = (ab, pred_rows, cell_trace, cell_pred, cell_thr, cell_hor,
+                cell_w, thresholds, horizon_wl, b, P_pad, bon_pad, boff_pad,
+                route)
+    return operands, sel, per_shard
+
+
+def _grid_map(local, operands, sel, *, mesh, axis, record):
+    """Run a sharded body's per-shard ``local`` over :func:`_grid_inputs`'
+    operands under ``shard_map``: traces, cell maps and the sweep axes
+    replicated, the level axis of every row sharded over ``axis``; every
+    output leaf replicated, then compacted from the storage layout back to
+    level order (a no-op slice for ungrouped fleets, where ``sel`` is
+    contiguous)."""
+    out_spec = {"x": P(), "energy": P(), "on_cost": P(), "off_cost": P()}
+    if record:
+        out_spec["decision_counts"] = P()
+    cell_spec = (P(),) * 5
+    fn = jax.shard_map(
+        local,
+        mesh=mesh,
+        in_specs=(P(), P()) + cell_spec
+        + (P(None, None, axis), P(None, axis), P(axis), P(axis), P(axis),
+           P(axis), P(axis)),
+        out_specs=out_spec,
+        check_vma=False,    # no replication rule for pallas_call yet
+    )
+    out = fn(*operands)
+    return {
+        k: (v if k == "x" else v[..., sel]) for k, v in out.items()
+    }
 
 
 @functools.partial(jax.jit, static_argnames=(
@@ -738,12 +824,13 @@ def _sharded_grid(ab, predb, windows, delta, P_lv, beta_on_lv, beta_off_lv,
 
     The demand/predicted traces and the per-cell wait tables are replicated
     only along the sweep axes; the *level* axis — thresholds, peek
-    horizons, Δ, cost fields — is sharded over the mesh.  Each shard runs
-    every grid cell over its level block through the Pallas grid scan
-    (interpret mode off-TPU); x(t) is a psum and the per-level cost terms a
-    tiled all_gather, so the caller sees (S, W, B, ...) leaves identical to
-    the unsharded engine.  Scales to fleets far past one host's memory
-    (1000+ node deployments decide locally, paper Sec. IV).
+    horizons, Δ, cost fields — is sharded over the mesh
+    (:func:`_grid_inputs`, :func:`_grid_map`).  Each shard runs every grid
+    cell over its level block through the Pallas grid scan (interpret mode
+    off-TPU); x(t) is a psum and the per-level cost terms a tiled
+    all_gather, so the caller sees (S, W, B, ...) leaves identical to the
+    unsharded engine.  Scales to fleets far past one host's memory (1000+
+    node deployments decide locally, paper Sec. IV).
 
     Typed fleets (``group_sizes``): levels are stored in the group-aligned
     layout of :func:`_group_layout` and every lane carries its *routing id*
@@ -762,73 +849,10 @@ def _sharded_grid(ab, predb, windows, delta, P_lv, beta_on_lv, beta_off_lv,
 
     S, B, T = predb.shape
     W = windows.shape[0]
-    size = mesh.shape[axis]
-    route_np, sel_np, n_layout = _group_layout(n_levels, group_sizes, size)
-    per_shard = n_layout // size
-    route = jnp.asarray(route_np)
-    sel = jnp.asarray(sel_np)
-
-    def pad_lv(v, fill):
-        # scatter a real (n_levels,) row into the storage layout; pad lanes
-        # take ``fill`` (they are masked out of every output anyway)
-        v = jnp.broadcast_to(jnp.asarray(v, jnp.float32), (n_levels,))
-        return jnp.full((n_layout,), fill, jnp.float32).at[sel].set(v)
-
-    b_real = jnp.broadcast_to(jnp.asarray(delta, jnp.float32), (n_levels,))
-    b = pad_lv(delta, 1.0)          # padded lanes are masked out; Δ irrelevant
-    wf = windows.astype(jnp.float32)
-    if policy in RANDOMIZED:
-        # draw at n_levels (NOT n_layout) so the (trace, key) -> schedule
-        # contract holds regardless of mesh size or group padding, then
-        # scatter the table into the layout; the same per-trace draws serve
-        # every window (common random numbers)
-        u0, u = jax.vmap(lambda k: _uniforms(k, T, n_levels))(keys)  # (B, T, N)
-        waits = jax.vmap(lambda w: jax.vmap(
-            lambda u0i, ui: _waits_from_uniforms(policy, u0i, ui, w, b_real)
-        )(u0, u))(wf)                                        # (W, B, T, N)
-        thresholds = (
-            jnp.zeros((W, B, T, n_layout), jnp.float32)
-            .at[..., sel].set(waits)
-            .reshape(W * B, T, n_layout)
-        )
-    elif policy == "AQ-rand":
-        # window-free randomized waits: one (T, N) table per trace serves
-        # the whole sweep (the AQ transform pins α = 0)
-        u0, u = jax.vmap(lambda k: _uniforms(k, T, n_levels))(keys)
-        waits = jax.vmap(
-            lambda u0i, ui: _waits_from_uniforms(policy, u0i, ui, 0, b_real)
-        )(u0, u)                                             # (B, T, N)
-        thresholds = (
-            jnp.zeros((B, T, n_layout), jnp.float32).at[..., sel].set(waits)
-        )
-    elif policy in ("delayedoff", "AQ-det"):
-        thresholds = jnp.broadcast_to(b, (W, n_layout))[:, None, :]  # timer Δ_l
-    else:                                                    # A1 per window
-        thresholds = jnp.maximum(0.0, b[None, :] - wf[:, None] - 1.0)[:, None, :]
-    if policy in NO_PEEK:
-        horizon_wl = jnp.zeros((W, n_layout), jnp.float32)   # no peek
-    else:
-        horizon_wl = jnp.minimum(wf[:, None] + 1.0, b[None, :])
-    P_pad = pad_lv(P_lv, 0.0)
-    bon_pad = pad_lv(beta_on_lv, 0.0)
-    boff_pad = pad_lv(beta_off_lv, 0.0)
-
-    # cell maps: cell g = (s, w, b) in row-major order, matching the
-    # (S, W, B) axis convention of _run_noise_sweep
-    s_ix, w_ix, b_ix = jnp.meshgrid(
-        jnp.arange(S), jnp.arange(W), jnp.arange(B), indexing="ij"
-    )
-    cell_trace = b_ix.reshape(-1).astype(jnp.int32)
-    cell_pred = (s_ix * B + b_ix).reshape(-1).astype(jnp.int32)
-    if policy in RANDOMIZED:
-        cell_thr = (w_ix * B + b_ix).reshape(-1).astype(jnp.int32)
-    elif policy == "AQ-rand":
-        cell_thr = b_ix.reshape(-1).astype(jnp.int32)        # per-trace tables
-    else:
-        cell_thr = w_ix.reshape(-1).astype(jnp.int32)
-    cell_hor = w_ix.reshape(-1).astype(jnp.int32)
-    cell_w = windows[w_ix.reshape(-1)]
-    pred_rows = predb.reshape(S * B, T)
+    operands, sel, per_shard = _grid_inputs(
+        ab, predb, windows, delta, P_lv, beta_on_lv, beta_off_lv, keys,
+        size=mesh.shape[axis], n_levels=n_levels, policy=policy,
+        group_sizes=group_sizes)
 
     def local(a_rows, p_rows, ct, cp, cthr, chor, cw, thr_l, hor_l, b_l,
               Pp, bon, boff, route_l):
@@ -876,71 +900,7 @@ def _sharded_grid(ab, predb, windows, delta, P_lv, beta_on_lv, beta_off_lv,
             )
         return terms
 
-    out_spec = {"x": P(), "energy": P(), "on_cost": P(), "off_cost": P()}
-    if record:
-        out_spec["decision_counts"] = P()
-    cell_spec = (P(),) * 5
-    fn = jax.shard_map(
-        local,
-        mesh=mesh,
-        in_specs=(P(), P()) + cell_spec
-        + (P(None, None, axis), P(None, axis), P(axis), P(axis), P(axis),
-           P(axis), P(axis)),
-        out_specs=out_spec,
-        check_vma=False,    # no replication rule for pallas_call yet
-    )
-    out = fn(ab, pred_rows, cell_trace, cell_pred, cell_thr, cell_hor, cell_w,
-             thresholds, horizon_wl, b, P_pad, bon_pad, boff_pad, route)
-    # compact the gathered storage layout back to level order (a no-op
-    # slice for ungrouped fleets, where sel is contiguous)
-    return {
-        k: (v if k == "x" else v[..., sel]) for k, v in out.items()
-    }
-
-
-def _sharded_stream(mesh, axis, ab, predb, windows, delta, P_lv, beta_on_lv,
-                    beta_off_lv, *, n_levels, max_h, policy, keys=None,
-                    use_pallas=True, group_sizes=None, t_chunk=None,
-                    record=False):
-    """Streaming twin of :func:`_sharded_run`: the level-sharded (S, W, B)
-    grid evaluated through the chunked kernels — the HBM-resident
-    double-buffered :func:`repro.kernels.provision_scan.provision_scan_stream`
-    on the Pallas route, :func:`_stream_cell` on the lax.scan route — so the
-    fleet path accepts production-length traces with an O(t_chunk + levels)
-    per-cell working set.  Same wait tables, cell maps and layout as the
-    monolithic grid (bit-exact on x and every cost leaf); per-slot decision
-    codes are never materialized (``record`` yields aggregate counters, the
-    existing fleet-path convention).
-    """
-    from repro.kernels.provision_scan import DEFAULT_T_CHUNK
-
-    _check_policy(policy)
-    if policy == "offline":
-        raise ValueError(
-            "sharded path supports online policies (offline has no slot scan); "
-            f"valid policies are {tuple(p for p in POLICIES if p != 'offline')}"
-        )
-    if policy in KEYED and keys is None:
-        _require_key(policy, None)
-    windows = jnp.asarray(windows, jnp.int32)
-    if policy in NO_PEEK:
-        h_unroll = 0
-    else:
-        try:
-            w_max = int(windows.max())
-        except jax.errors.ConcretizationTypeError:
-            w_max = max_h                       # masked peek bound (see above)
-        h_unroll = int(min(w_max + 1, max_h))
-    if t_chunk is None:
-        t_chunk = DEFAULT_T_CHUNK
-    t_chunk = int(min(t_chunk, max(int(ab.shape[-1]), 1)))
-    return _sharded_stream_grid(
-        jnp.asarray(ab), jnp.asarray(predb), windows, delta, P_lv,
-        beta_on_lv, beta_off_lv, keys,
-        mesh=mesh, axis=axis, n_levels=n_levels, max_h=max_h,
-        h_unroll=h_unroll, policy=policy, use_pallas=use_pallas,
-        group_sizes=group_sizes, t_chunk=t_chunk, record=record,
-    )
+    return _grid_map(local, operands, sel, mesh=mesh, axis=axis, record=record)
 
 
 @functools.partial(jax.jit, static_argnames=(
@@ -952,75 +912,30 @@ def _sharded_stream_grid(ab, predb, windows, delta, P_lv, beta_on_lv,
                          t_chunk, record=False):
     """One device program for the streaming sharded grid.
 
-    Identical sweep/layout/threshold construction to :func:`_sharded_grid`
-    — same CRN draws, same group-aligned routed lanes — but each shard
-    reduces its level block through the streaming kernels, which return
-    x(t), per-lane accumulators and the end-of-trace carry instead of the
-    (G, T, per_shard) on-matrix.  The forced x(T) = a(T) final off is
-    applied here from the carry (the kernel contract leaves it to the
-    caller, who alone knows the trace really ends at T).
+    The level-sharded (S, W, B) grid evaluated through the chunked kernels —
+    the HBM-resident double-buffered
+    :func:`repro.kernels.provision_scan.provision_scan_stream` on the
+    Pallas route, :func:`_stream_cell` on the lax.scan route — so the fleet
+    path accepts production-length traces with an O(t_chunk + levels)
+    per-cell working set.  The same inputs and ``shard_map`` as
+    :func:`_sharded_grid` (:func:`_grid_inputs`, :func:`_grid_map`: same
+    CRN draws, same group-aligned routed lanes; bit-exact on x and every
+    cost leaf), but each shard reduces its level block to x(t), per-lane
+    accumulators and the end-of-trace carry instead of the (G, T,
+    per_shard) on-matrix.  The forced x(T) = a(T) final off is applied
+    here from the carry (the kernel contract leaves it to the caller, who
+    alone knows the trace really ends at T).  Per-slot decision codes are
+    never materialized (``record`` yields aggregate counters, the fleet
+    path's convention).
     """
     from repro.kernels.provision_scan import provision_scan_stream
 
     S, B, T = predb.shape
     W = windows.shape[0]
-    size = mesh.shape[axis]
-    route_np, sel_np, n_layout = _group_layout(n_levels, group_sizes, size)
-    per_shard = n_layout // size
-    route = jnp.asarray(route_np)
-    sel = jnp.asarray(sel_np)
-
-    def pad_lv(v, fill):
-        v = jnp.broadcast_to(jnp.asarray(v, jnp.float32), (n_levels,))
-        return jnp.full((n_layout,), fill, jnp.float32).at[sel].set(v)
-
-    b_real = jnp.broadcast_to(jnp.asarray(delta, jnp.float32), (n_levels,))
-    b = pad_lv(delta, 1.0)
-    wf = windows.astype(jnp.float32)
-    if policy in RANDOMIZED:
-        u0, u = jax.vmap(lambda k: _uniforms(k, T, n_levels))(keys)  # (B, T, N)
-        waits = jax.vmap(lambda w: jax.vmap(
-            lambda u0i, ui: _waits_from_uniforms(policy, u0i, ui, w, b_real)
-        )(u0, u))(wf)                                        # (W, B, T, N)
-        thresholds = (
-            jnp.zeros((W, B, T, n_layout), jnp.float32)
-            .at[..., sel].set(waits)
-            .reshape(W * B, T, n_layout)
-        )
-    elif policy == "AQ-rand":
-        u0, u = jax.vmap(lambda k: _uniforms(k, T, n_levels))(keys)
-        waits = jax.vmap(
-            lambda u0i, ui: _waits_from_uniforms(policy, u0i, ui, 0, b_real)
-        )(u0, u)                                             # (B, T, N)
-        thresholds = (
-            jnp.zeros((B, T, n_layout), jnp.float32).at[..., sel].set(waits)
-        )
-    elif policy in ("delayedoff", "AQ-det"):
-        thresholds = jnp.broadcast_to(b, (W, n_layout))[:, None, :]  # timer Δ_l
-    else:                                                    # A1 per window
-        thresholds = jnp.maximum(0.0, b[None, :] - wf[:, None] - 1.0)[:, None, :]
-    if policy in NO_PEEK:
-        horizon_wl = jnp.zeros((W, n_layout), jnp.float32)
-    else:
-        horizon_wl = jnp.minimum(wf[:, None] + 1.0, b[None, :])
-    P_pad = pad_lv(P_lv, 0.0)
-    bon_pad = pad_lv(beta_on_lv, 0.0)
-    boff_pad = pad_lv(beta_off_lv, 0.0)
-
-    s_ix, w_ix, b_ix = jnp.meshgrid(
-        jnp.arange(S), jnp.arange(W), jnp.arange(B), indexing="ij"
-    )
-    cell_trace = b_ix.reshape(-1).astype(jnp.int32)
-    cell_pred = (s_ix * B + b_ix).reshape(-1).astype(jnp.int32)
-    if policy in RANDOMIZED:
-        cell_thr = (w_ix * B + b_ix).reshape(-1).astype(jnp.int32)
-    elif policy == "AQ-rand":
-        cell_thr = b_ix.reshape(-1).astype(jnp.int32)
-    else:
-        cell_thr = w_ix.reshape(-1).astype(jnp.int32)
-    cell_hor = w_ix.reshape(-1).astype(jnp.int32)
-    cell_w = windows[w_ix.reshape(-1)]
-    pred_rows = predb.reshape(S * B, T)
+    operands, sel, per_shard = _grid_inputs(
+        ab, predb, windows, delta, P_lv, beta_on_lv, beta_off_lv, keys,
+        size=mesh.shape[axis], n_levels=n_levels, policy=policy,
+        group_sizes=group_sizes)
 
     def local(a_rows, p_rows, ct, cp, cthr, chor, cw, thr_l, hor_l, b_l,
               Pp, bon, boff, route_l):
@@ -1072,180 +987,4 @@ def _sharded_stream_grid(ab, predb, windows, delta, P_lv, beta_on_lv,
             )
         return terms
 
-    out_spec = {"x": P(), "energy": P(), "on_cost": P(), "off_cost": P()}
-    if record:
-        out_spec["decision_counts"] = P()
-    cell_spec = (P(),) * 5
-    fn = jax.shard_map(
-        local,
-        mesh=mesh,
-        in_specs=(P(), P()) + cell_spec
-        + (P(None, None, axis), P(None, axis), P(axis), P(axis), P(axis),
-           P(axis), P(axis)),
-        out_specs=out_spec,
-        check_vma=False,
-    )
-    out = fn(ab, pred_rows, cell_trace, cell_pred, cell_thr, cell_hor, cell_w,
-             thresholds, horizon_wl, b, P_pad, bon_pad, boff_pad, route)
-    return {
-        k: (v if k == "x" else v[..., sel]) for k, v in out.items()
-    }
-
-
-# ---------------------------------------------------------------------------
-# Deprecated loose-kwargs API (forwards to the spec engine)
-# ---------------------------------------------------------------------------
-
-def _warn_deprecated(old: str, new: str) -> None:
-    warnings.warn(
-        f"deprecated: {old} — build a ProvisionSpec and call "
-        f"repro.core.provision ({new})",
-        DeprecationWarning,
-        stacklevel=3,
-    )
-
-
-def _dynamics_costs(delta):
-    """A CostModel whose derived Δ equals the wrapper's free-floating delta."""
-    from .costs import CostModel
-
-    d = jnp.asarray(delta, jnp.float32)
-    half = d / 2.0 if d.ndim else float(delta) / 2.0
-    return CostModel(P=1.0, beta_on=half, beta_off=half)
-
-
-def provision_schedule(
-    a: jax.Array,          # (T,) or (B, T) int32 demand per slot
-    *,
-    n_levels: int,
-    delta: int,            # critical interval in slots (beta/P)
-    window: int = 0,       # future slots visible (current slot always known)
-    policy: str = "A1",    # A1 | A2 | A3 | offline | delayedoff
-    predicted: jax.Array | None = None,
-    key: jax.Array | None = None,   # required for A2/A3; split per trace if batched
-) -> jax.Array:
-    """Deprecated: use ``provision(ProvisionSpec(...))``.
-
-    Returns x: (T,) or (B, T) int32 — number of powered-on servers per slot.
-    """
-    from .provision import PolicySpec, ProvisionSpec, Workload, provision
-
-    _warn_deprecated("provision_schedule(...)", "result.x")
-    spec = ProvisionSpec(
-        costs=_dynamics_costs(delta),
-        workload=Workload(demand=a, predicted=predicted),
-        policy=PolicySpec(name=policy, window=window, key=key),
-        n_levels=n_levels,
-    )
-    return provision(spec).x
-
-
-def provision_sweep(
-    a: jax.Array,
-    *,
-    n_levels: int,
-    delta: int,
-    windows: jax.Array,    # (W,) prediction windows in slots; α = (w+1)/Δ
-    policy: str = "A1",
-    key: jax.Array | None = None,
-    predicted: jax.Array | None = None,
-) -> jax.Array:
-    """Deprecated: use ``provision(ProvisionSpec(...))`` with ``windows=``.
-
-    x over the whole sweep: (W, T) for a (T,) trace, (W, B, T) batched.
-    """
-    from .provision import PolicySpec, ProvisionSpec, Workload, provision
-
-    _warn_deprecated("provision_sweep(...)", "result.x with a windows axis")
-    spec = ProvisionSpec(
-        costs=_dynamics_costs(delta),
-        workload=Workload(demand=a, predicted=predicted),
-        policy=PolicySpec(name=policy, windows=windows, key=key),
-        n_levels=n_levels,
-    )
-    return provision(spec).x
-
-
-def provision_sweep_costs(
-    a: jax.Array,
-    *,
-    n_levels: int,
-    delta: int,
-    windows: jax.Array,
-    policy: str = "A1",
-    key: jax.Array | None = None,
-    predicted: jax.Array | None = None,
-    P: float = 1.0,
-    beta_on: float = 3.0,
-    beta_off: float = 3.0,
-) -> jax.Array:
-    """Deprecated: use ``provision(ProvisionSpec(...))`` and ``result.cost``.
-
-    Schedule costs over the sweep: (W,) or (W, B) — one device program.
-    The redundant ``delta`` kwarg must equal the derived
-    ``(beta_on + beta_off) / P`` (the spec API removes it entirely).
-    """
-    from .costs import CostModel
-    from .provision import PolicySpec, ProvisionSpec, Workload, provision
-
-    _warn_deprecated("provision_sweep_costs(...)", "result.cost with a windows axis")
-    derived = (beta_on + beta_off) / P
-    if abs(derived - float(delta)) > 1e-6:
-        raise ValueError(
-            f"delta={delta} disagrees with (beta_on+beta_off)/P={derived}; "
-            "the spec API derives delta from CostModel — drop the delta kwarg"
-        )
-    spec = ProvisionSpec(
-        costs=CostModel(P=P, beta_on=beta_on, beta_off=beta_off),
-        workload=Workload(demand=a, predicted=predicted),
-        policy=PolicySpec(name=policy, windows=windows, key=key),
-        n_levels=n_levels,
-    )
-    return provision(spec).cost
-
-
-def provision_cost(
-    a: jax.Array, on_matrix: jax.Array, P: float, beta_on: float, beta_off: float
-) -> jax.Array:
-    """Deprecated: use ``on_matrix_cost(a, on_matrix, CostModel(...))`` or the
-    ``cost``/``level_cost`` fields of a :func:`provision` result.
-
-    Total cost of a per-level schedule (energy + toggles + forced final off).
-    Supports leading batch axes: ``a`` (..., T), ``on_matrix`` (..., T, N).
-    """
-    from .costs import CostModel
-
-    _warn_deprecated("provision_cost(...)", "result.cost / on_matrix_cost")
-    return on_matrix_cost(a, on_matrix, CostModel(P=P, beta_on=beta_on, beta_off=beta_off))
-
-
-def provision_schedule_sharded(
-    mesh: Mesh,
-    a: jax.Array,
-    *,
-    n_levels: int,
-    delta: int,
-    window: int = 0,
-    axis: str = "data",
-    policy: str = "A1",
-    key: jax.Array | None = None,
-    predicted: jax.Array | None = None,
-    use_pallas: bool = True,
-) -> jax.Array:
-    """Deprecated: use ``provision(ProvisionSpec(..., mesh=mesh))``.
-
-    Same as provision_schedule, levels sharded over ``axis`` via shard_map.
-    """
-    from .provision import PolicySpec, ProvisionSpec, Workload, provision
-
-    _warn_deprecated("provision_schedule_sharded(...)", "mesh= on the spec")
-    spec = ProvisionSpec(
-        costs=_dynamics_costs(delta),
-        workload=Workload(demand=a, predicted=predicted),
-        policy=PolicySpec(name=policy, window=window, key=key),
-        n_levels=n_levels,
-        mesh=mesh,
-        mesh_axis=axis,
-        use_pallas=use_pallas,
-    )
-    return provision(spec).x
+    return _grid_map(local, operands, sel, mesh=mesh, axis=axis, record=record)

@@ -388,7 +388,8 @@ def _layout_gauges(tel, spec: ProvisionSpec, pr: dict, policy: str) -> None:
     on a live registry: ``provision/layout_lanes`` and
     ``provision/layout_pad_lanes`` (the group-aligned lanes and how many
     of them are pad), ``provision/wait_table_bytes`` (the time-varying
-    threshold table the kernel reads; 0 for constant thresholds)."""
+    (K, T, lanes) float32 threshold table the kernel reads, K from
+    ``_n_wait_tables``; 0 for constant thresholds)."""
     if spec.mesh is None or not tel.enabled:
         return
     n_layout, pad = _engine._layout_lanes(
@@ -396,18 +397,15 @@ def _layout_gauges(tel, spec: ProvisionSpec, pr: dict, policy: str) -> None:
     B, T = pr["ab"].shape
     tel.gauge("provision/layout_lanes", n_layout)
     tel.gauge("provision/layout_pad_lanes", pad)
-    tel.gauge("provision/wait_table_bytes", _engine._wait_table_bytes(
-        policy, pr["windows"].shape[0], B, T, n_layout))
+    tel.gauge("provision/wait_table_bytes", 4 * T * n_layout
+              * _engine._n_wait_tables(policy, pr["windows"].shape[0], B))
 
 
 def provision(spec: ProvisionSpec, *, record_decisions: bool = False) -> ProvisionResult:
     """Run a :class:`ProvisionSpec` end-to-end as one jitted device program.
 
-    Subsumes the deprecated ``provision_schedule`` / ``provision_sweep`` /
-    ``provision_sweep_costs`` / ``provision_cost`` /
-    ``provision_schedule_sharded`` surface: batching is the demand's leading
-    axis, the α-sweep is ``PolicySpec.windows``, sharding is ``mesh=``.  The
-    cost model's fields flow through jit as data, so re-pricing the fleet
+    Batching is the demand's leading axis, the α-sweep is
+    ``PolicySpec.windows``, sharding is ``mesh=``.  The cost model's fields flow through jit as data, so re-pricing the fleet
     does not recompile; only (policy, shapes, Δ's static scan bound) do.
 
     ``record_decisions=True`` fills ``ProvisionResult.decisions`` /
@@ -539,7 +537,7 @@ def provision_stream(
             if spec.mesh is not None:
                 ab, predb, *rest = engine_in
                 predb3 = predb[None] if predb.ndim == 2 else predb
-                out = _engine._sharded_stream(
+                out = _engine._sharded_run(
                     spec.mesh, spec.mesh_axis, ab, predb3, *rest,
                     n_levels=n_levels, max_h=pr["max_h"], policy=pol.name,
                     keys=pr["keys"], use_pallas=spec.use_pallas,

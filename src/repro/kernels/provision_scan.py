@@ -521,8 +521,10 @@ def provision_scan_stream(
       in tests/test_streaming.py).
 
     ``T`` need not be a multiple of ``t_chunk`` — the pad tail freezes the
-    carry.  The compiled route rounds ``t_chunk`` up to a multiple of 128
-    (Mosaic moves rows in whole lane tiles); results never depend on it.
+    carry; a ``t_chunk`` past ``T`` only pads the one tile
+    (``provision_stream`` clamps it to the trace).  The compiled route
+    rounds ``t_chunk`` up to a multiple of 128 (Mosaic moves rows in whole
+    lane tiles); results never depend on it.
     The peek reads ``horizon`` extra slots of each predicted tile, so a
     chunk boundary never truncates the lookahead *within one call*; across
     calls the caller chooses where to split (``provision_stream`` streams
@@ -533,7 +535,6 @@ def provision_scan_stream(
     assert traces.ndim == 2 and predicted.ndim == 2, (traces.shape, predicted.shape)
     T = traces.shape[1]
     interpret = _resolve_interpret(interpret)
-    t_chunk = int(min(t_chunk, max(T, 1)))
     if not interpret:
         # Mosaic slices a row tile only in whole 128-lane units
         t_chunk = -(-t_chunk // LANE) * LANE

@@ -5,11 +5,12 @@ pattern that used to be hand-rolled in three places (the eval harness's
 ``_engine_cache_size``, ``benchmarks/provision_bench.py``'s cache gates,
 and ``benchmarks/cr_eval.py``'s mesh smoke): snapshot the compiled-program
 count of a set of jitted functions, run something, and report how many
-programs the run added.  The engine's entrypoints (``_run``,
-``_run_noise_sweep``, ``_sharded_grid`` and their streaming twins
-``_run_stream``, ``_run_stream_noise``, ``_sharded_stream_grid``) are
-separate jitted functions *precisely so* their compiles are observable
-here.
+programs the run added.  The engine's entrypoints — one jitted body per
+route: ``_run``/``_run_noise_sweep`` and their streaming twins
+``_run_stream``/``_run_stream_noise`` (which share ``_scan_sweep``),
+``_sharded_grid`` and ``_sharded_stream_grid`` (which share
+``_grid_inputs``/``_grid_map``) — are separate jitted functions
+*precisely so* their compiles are observable here.
 
 The count rides JAX's private ``_cache_size`` API; when that API is gone
 the watcher degrades exactly like the code it replaced: ``snapshot()``

@@ -728,3 +728,53 @@ def test_aq_rand_respects_per_type_bound_in_expectation():
     bound = math.e / (math.e - 1.0)
     assert (mean_group <= opt_group * (bound + 0.15)).all(), (
         f"per-type mean cost {mean_group} vs offline {opt_group}")
+
+
+# ---------------------------------------------------------------------------
+# One input through every planning route
+# ---------------------------------------------------------------------------
+
+#: two server types, the second with a fractional critical interval
+#: (Δ = 4 and Δ = 5.25 / 1.5 = 3.5), so waits, peek reach and the group-
+#: aligned mesh layout all differ per type
+TWO_TYPES = CostModel.from_groups(
+    ServerGroup("efficient", 5, P=1.0, beta_on=2.0, beta_off=2.0),
+    ServerGroup("legacy", 4, P=1.5, beta_on=2.5, beta_off=2.75),
+)
+
+
+@pytest.mark.parametrize("policy", ["A1", "A2", "A3", "delayedoff", "AQ-det",
+                                    "AQ-rand"])
+def test_every_planning_route_agrees(policy):
+    """provision and provision_stream, each unsharded and on a one-device
+    mesh through both the Pallas kernel and the lax.scan body, give the
+    same x, level_cost and group_cost bit for bit: the shared wait draws,
+    scan sweep and grid layout feed every route the same decisions."""
+    from repro.core import provision_stream
+    from repro.core.jax_provision import KEYED
+
+    ab = np.stack([_typed_trace(s, n_slots=48) for s in (31, 32)])
+    mesh = jax.make_mesh((1,), ("data",))
+    spec = ProvisionSpec(
+        costs=TWO_TYPES,
+        workload=Workload(demand=jnp.asarray(ab, jnp.int32)),
+        policy=PolicySpec(policy, windows=jnp.asarray([0, 1, 3], jnp.int32),
+                          key=jax.random.key(4) if policy in KEYED else None),
+        n_levels=TWO_TYPES.n_levels,
+    )
+    routes = {"provision": provision(spec)}
+    for use_pallas in (True, False):
+        routes[f"provision mesh use_pallas={use_pallas}"] = provision(
+            dataclasses.replace(spec, mesh=mesh, use_pallas=use_pallas))
+    routes["provision_stream"] = provision_stream(spec, t_chunk=16)
+    for use_pallas in (True, False):
+        routes[f"provision_stream mesh use_pallas={use_pallas}"] = provision_stream(
+            dataclasses.replace(spec, mesh=mesh, use_pallas=use_pallas),
+            t_chunk=16)
+    want = routes.pop("provision")
+    assert want.x.shape == (3, 2, 48) and want.group_cost.shape == (3, 2, 2)
+    for name, got in routes.items():
+        for field in ("x", "level_cost", "group_cost"):
+            np.testing.assert_array_equal(
+                np.asarray(getattr(got, field)), np.asarray(getattr(want, field)),
+                err_msg=f"{name}: {field}")

@@ -2,12 +2,10 @@
 
 Covers the heterogeneous-cost reduction laws (per-level arrays that all
 share ``PAPER_COSTS`` must reproduce the homogeneous ``fluid_cost`` /
-``fluid_scan`` / ``schedule_cost`` numbers), the per-level-group
-decomposition of genuinely heterogeneous fleets, and the deprecated
-loose-kwargs wrappers (must warn AND return bit-identical results).
+``fluid_scan`` / ``schedule_cost`` numbers) and the per-level-group
+decomposition of genuinely heterogeneous fleets.
 """
 import dataclasses
-import warnings
 
 import jax
 import jax.numpy as jnp
@@ -27,7 +25,6 @@ from repro.core import (
     Workload,
     fluid_cost,
     fluid_scan,
-    on_matrix_cost,
     provision,
     schedule_cost,
 )
@@ -188,91 +185,6 @@ def test_cost_model_validation():
         policy=PolicySpec("A1"),
     ))
     assert res.level_cost.shape == (2,)
-
-
-# ---------------------------------------------------------------------------
-# Deprecated wrappers: warn, and forward bit-identically
-# ---------------------------------------------------------------------------
-
-def _no_warn_provision(spec):
-    with warnings.catch_warnings():
-        warnings.simplefilter("error", DeprecationWarning)
-        return provision(spec)
-
-
-def test_provision_schedule_wrapper_warns_and_matches():
-    from repro.core import provision_schedule
-
-    rng = np.random.default_rng(20)
-    a = rng.integers(0, 7, size=80)
-    n = int(a.max()) + 1
-    key = jax.random.key(1)
-    for policy in ("A1", "A3", "offline", "delayedoff"):
-        k = key if policy == "A3" else None
-        with pytest.warns(DeprecationWarning, match="^deprecated"):
-            old = provision_schedule(jnp.asarray(a, jnp.int32), n_levels=n,
-                                     delta=6, window=2, policy=policy, key=k)
-        new = _no_warn_provision(spec_for(a, PAPER_COSTS, policy, window=2,
-                                          key=k, n_levels=n))
-        np.testing.assert_array_equal(np.asarray(old), np.asarray(new.x))
-
-
-def test_provision_sweep_wrappers_warn_and_match():
-    from repro.core import provision_sweep, provision_sweep_costs
-
-    rng = np.random.default_rng(21)
-    ab = rng.integers(0, 6, size=(3, 60))
-    windows = jnp.arange(4)
-    key = jax.random.key(2)
-    with pytest.warns(DeprecationWarning, match="^deprecated"):
-        old_x = provision_sweep(jnp.asarray(ab, jnp.int32), n_levels=6, delta=6,
-                                windows=windows, policy="A3", key=key)
-    with pytest.warns(DeprecationWarning, match="^deprecated"):
-        old_c = provision_sweep_costs(jnp.asarray(ab, jnp.int32), n_levels=6,
-                                      delta=6, windows=windows, policy="A3",
-                                      key=key, P=1.0, beta_on=3.0, beta_off=3.0)
-    new = _no_warn_provision(spec_for(ab, PAPER_COSTS, "A3", windows=windows,
-                                      key=key, n_levels=6))
-    np.testing.assert_array_equal(np.asarray(old_x), np.asarray(new.x))
-    np.testing.assert_array_equal(np.asarray(old_c), np.asarray(new.cost))
-
-
-def test_provision_sweep_costs_rejects_inconsistent_delta():
-    from repro.core import provision_sweep_costs
-
-    with pytest.warns(DeprecationWarning, match="^deprecated"):
-        with pytest.raises(ValueError, match="disagrees"):
-            provision_sweep_costs(jnp.ones((10,), jnp.int32), n_levels=2,
-                                  delta=7, windows=jnp.arange(2),
-                                  P=1.0, beta_on=3.0, beta_off=3.0)
-
-
-def test_provision_cost_wrapper_warns_and_matches():
-    from repro.core import provision_cost
-    from repro.core.jax_provision import _level_schedule
-
-    rng = np.random.default_rng(22)
-    a = rng.integers(0, 6, size=50)
-    ons = _level_schedule(jnp.asarray(a, jnp.int32), 6, 6, 1, "A1")
-    with pytest.warns(DeprecationWarning, match="^deprecated"):
-        old = provision_cost(jnp.asarray(a), ons, 1.0, 3.0, 3.0)
-    new = on_matrix_cost(jnp.asarray(a), ons, PAPER_COSTS)
-    np.testing.assert_array_equal(np.asarray(old), np.asarray(new))
-
-
-def test_provision_schedule_sharded_wrapper_warns_and_matches():
-    from repro.core import provision_schedule_sharded
-
-    rng = np.random.default_rng(23)
-    a = rng.integers(0, 6, size=60)
-    n = int(a.max()) + 1
-    mesh = jax.make_mesh((len(jax.devices()),), ("data",))
-    with pytest.warns(DeprecationWarning, match="^deprecated"):
-        old = provision_schedule_sharded(mesh, jnp.asarray(a, jnp.int32),
-                                         n_levels=n, delta=6, window=2)
-    new = _no_warn_provision(dataclasses.replace(
-        spec_for(a, PAPER_COSTS, "A1", window=2, n_levels=n), mesh=mesh))
-    np.testing.assert_array_equal(np.asarray(old), np.asarray(new.x))
 
 
 # ---------------------------------------------------------------------------
